@@ -331,9 +331,9 @@ def _mark_roots(mi: ModuleInfo) -> None:
             fi.seeded = True
 
     # functions handed to jax.jit(...) or a tracing wrapper call.  The
-    # leading-underscore strip covers import aliases like the compat
-    # shim's ``shard_map as _shard_map`` (repro.core.compat consumers):
-    # the aliased call must still mark its payload as a traced root.
+    # leading-underscore strip covers import aliases like
+    # ``shard_map as _shard_map``: the aliased call must still mark its
+    # payload as a traced root.
     for node in ast.walk(mi.tree):
         if not isinstance(node, ast.Call) or not node.args:
             continue

@@ -1,25 +1,30 @@
 """Kernel contract checker: static proofs over every ``pallas_call``.
 
-ROADMAP item 5 (running the SMEM-cursor pair kernel on real TPUs) should
-start from machine-checked contracts, not interpret-parity hope.  This
-pass proves, for every kernel entry point in ``kernels/*/kernel.py`` and
+Machine-checked kernel contracts, so a block shape the TPU compiler
+would refuse is caught on the CPU, before any chip run.  This pass
+proves, for every kernel entry point in ``kernels/*/kernel.py`` and
 over the *reachable shape lattice* — pow-2 capacities (the serving stack
 quantizes every table axis with ``runtime.straggler.quantize_pow2``,
 floor 8) × all ``choose_tiles`` outputs × slot-stack depths:
 
 KC101  tile divisibility: the padded capacity each op wrapper feeds the
        kernel is an exact tile multiple and every grid extent is ≥ 1;
-KC102  tile alignment: TA is a sublane (8) multiple and TB a lane (128)
-       multiple — the int32 VREG granularity from the Pallas TPU guide;
+KC102  block shapes the TPU compiler accepts: every VMEM block's last
+       two (non-squeezed) dims are (sublane, 128)-divisible or equal to
+       the array's — sublane 8 for int32, 32 for the int8 mask — and a
+       1-D block is the whole array (``block_shape_finding``; the rule
+       Mosaic enforces, which a ``(tile,)`` int32 block or a ``(1, tile)``
+       per-slot block violates);
 KC103  index-map bounds: each BlockSpec's ``index_map`` (mirrored here,
        declaratively, from the kernel source) stays in bounds for every
        grid point — ``index*block + block <= padded array dim`` on every
        axis, including the data-dependent embedding-bag maps, which are
        proven by interval argument from their documented preconditions;
-KC104  SMEM cursor safety for ``compat_join_pairs``: the emit clamp
+KC104  cursor safety for ``compat_join_pairs``: the emit clamp
        ``n_emit = min(n_tile, max(max_new - base, 0))`` implies every
-       write lands strictly below ``max_new`` for any base in
-       [0, CA·CB] and any per-tile count in [0, TA·TB] — checked
+       write lands strictly below ``max_new`` — and inside the
+       ``[out_rows(max_new), 128]`` output block — for any base in
+       [0, CA·CB] and any per-tile count in [0, TA·TB]; checked
        algebraically at the interval extremes, after asserting the
        clamp expression is actually present in the kernel source;
 KC105  kernel-vs-ref agreement: ``jax.eval_shape`` abstract evaluation
@@ -48,8 +53,7 @@ from repro.analysis.findings import ERROR, WARNING, Finding
 # Entry points with a declarative contract below.  KC100 fires for any
 # pallas_call in kernels/*/kernel.py outside these functions.
 MODELED_ENTRY_POINTS = frozenset({
-    "compat_mask_kernel", "compat_mask_kernel_batched",
-    "compat_join_pairs_kernel", "compat_join_pairs_kernel_batched",
+    "compat_mask_kernel", "compat_join_pairs_kernel",
     "segment_sum_kernel", "embedding_bag_kernel",
 })
 
@@ -60,16 +64,11 @@ CAPS_FAST = (8, 64, 256, 4096)
 SLOTS = (1, 2, 4, 8)
 MAX_NEW = (64, 256, 1024, 4096)
 WIDTHS = (1, 2, 3, 4)                                    # nv / ne columns
+_LANE = 128                                              # TPU lane width
 
-# Representative batched-flag sets for the stacked kernels: all-shared,
-# all-per-slot, and each one-sided mix (the slot tick's stream-edge
-# operand is the canonical shared side).
-FLAG_SETS = (
-    (False,) * 6,
-    (True,) * 6,
-    (True, True, True, False, False, False),
-    (False, False, False, True, True, True),
-)
+# Representative (a_batched, b_batched) sets for the packed operands:
+# both shared (an unbatched call), both per-slot, and each one-sided mix.
+FLAG_SETS = ((False, False), (True, True), (True, False), (False, True))
 
 
 def _finding(rule, severity, symbol, message, path="", line=0):
@@ -121,73 +120,83 @@ def discover_pallas_sites(kernels_root: str) -> list[tuple[str, str, int]]:
 def _bounds_ok(grid, specs):
     """Exhaustively check index*block + block <= dim for every grid
     point.  ``specs`` is [(name, array_shape, block_shape, index_map)]
-    with index_map taking the grid tuple and returning block indices."""
+    with index_map taking the grid tuple and returning block indices
+    (a squeezed ``None`` block dim indexes single elements)."""
     bad = []
     for point in itertools.product(*(range(g) for g in grid)):
         for name, array_shape, block_shape, index_map in specs:
             idx = index_map(*point)
             for ax, (i, b, dim) in enumerate(
                     zip(idx, block_shape, array_shape)):
+                b = 1 if b is None else b
                 if i < 0 or i * b + b > dim:
                     bad.append((name, point, ax, i, b, dim))
     return bad
 
 
-def _compat_specs(cap, cbp, ta, tb, widths, max_new=None):
-    """Unbatched 2-D-grid specs, mirroring compat_*_kernel."""
-    nva, nea, nvb, neb = widths
-    specs = [
-        ("bind_a", (cap, nva), (ta, nva), lambda i, j: (i, 0)),
-        ("ets_a", (cap, nea), (ta, nea), lambda i, j: (i, 0)),
-        ("valid_a", (cap,), (ta,), lambda i, j: (i,)),
-        ("bind_b", (cbp, nvb), (tb, nvb), lambda i, j: (j, 0)),
-        ("ets_b", (cbp, neb), (tb, neb), lambda i, j: (j, 0)),
-        ("valid_b", (cbp,), (tb,), lambda i, j: (j,)),
-    ]
-    if max_new is None:
-        specs.append(("mask_out", (cap, cbp), (ta, tb),
-                      lambda i, j: (i, j)))
+def block_shape_finding(name, array_shape, block_shape, sublane=8):
+    """KC102 for one VMEM block: the rule Mosaic enforces when it lowers
+    a ``pallas_call`` for the TPU (``None`` dims are squeezed away).
+
+    * 2-D and up: the block's second-to-last dim is a multiple of the
+      dtype's sublane tile (8 for int32, 32 for int8) or equals the
+      array's dim, and its last dim is a multiple of 128 or equals the
+      array's dim;
+    * 1-D: the block is the whole array — a ``(tile,)`` block of a
+      longer 1-D array gets a Mosaic tiling that does not match XLA's
+      layout of the operand, and the compile is refused.
+
+    Returns a ``Finding`` for a violating block, else None.
+    """
+    dims = [(a, b) for a, b in zip(array_shape, block_shape)
+            if b is not None]
+    if len(dims) == 1:
+        (a1, b1), = dims
+        ok = b1 == a1
     else:
-        specs += [
-            ("a_out", (max_new,), (max_new,), lambda i, j: (0,)),
-            ("b_out", (max_new,), (max_new,), lambda i, j: (0,)),
-            ("n_out", (1,), (1,), lambda i, j: (0,)),
-        ]
-    return specs
+        (a2, b2), (a1, b1) = dims[-2], dims[-1]
+        ok = ((b2 % sublane == 0 or b2 == a2)
+              and (b1 % _LANE == 0 or b1 == a1))
+    if ok:
+        return None
+    return _finding(
+        "KC102", ERROR, name,
+        f"block {tuple(block_shape)} of array {tuple(array_shape)} breaks "
+        f"the TPU block rule (last two dims divisible by ({sublane}, "
+        f"{_LANE}) or equal to the array's; a 1-D block must be the "
+        f"whole array)")
 
 
-def _compat_specs_batched(n_slots, cap, cbp, ta, tb, widths, flags,
-                          max_new=None):
-    """Stacked 3-D-grid specs, mirroring _stacked_in_specs: batched
-    inputs carry [S] and a slot-aware index_map; shared inputs keep the
-    2-D map that ignores the slot coordinate."""
+def _compat_specs(n_slots, cap, cbp, ta, tb, widths, a_batched, b_batched,
+                  max_new=None):
+    """The 3-D-grid ``(slot, A-tile, B-tile)`` specs of both compat_join
+    kernels, mirrored from ``kernel._grid_spec`` and the out_specs:
+    ``(name, array_shape, block_shape, index_map, sublane)``.  Packed
+    operands are ``[S?, K, C]`` (rows on lanes); a per-slot operand has
+    a squeezed slot dim, a shared one ignores the slot coordinate."""
+    from repro.kernels.compat_join.kernel import out_rows
     nva, nea, nvb, neb = widths
-    base = [
-        ("bind_a", (cap, nva), (ta, nva), lambda s, i, j: (i, 0)),
-        ("ets_a", (cap, nea), (ta, nea), lambda s, i, j: (i, 0)),
-        ("valid_a", (cap,), (ta,), lambda s, i, j: (i,)),
-        ("bind_b", (cbp, nvb), (tb, nvb), lambda s, i, j: (j, 0)),
-        ("ets_b", (cbp, neb), (tb, neb), lambda s, i, j: (j, 0)),
-        ("valid_b", (cbp,), (tb,), lambda s, i, j: (j,)),
-    ]
+    ka, kb = nva + nea + 1, nvb + neb + 1
     specs = []
-    for flag, (name, shape, block, idx) in zip(flags, base):
-        if flag:
-            specs.append((name, (n_slots,) + shape, (1,) + block,
-                          lambda s, i, j, idx=idx: (s,) + idx(s, i, j)))
+    for name, k, c, t, batched, idx in (
+            ("a", ka, cap, ta, a_batched, lambda i, j: i),
+            ("b", kb, cbp, tb, b_batched, lambda i, j: j)):
+        if batched:
+            specs.append((name, (n_slots, k, c), (None, k, t),
+                          lambda s, i, j, idx=idx: (s, 0, idx(i, j)), 8))
         else:
-            specs.append((name, shape, block, idx))
+            specs.append((name, (k, c), (k, t),
+                          lambda s, i, j, idx=idx: (0, idx(i, j)), 8))
     if max_new is None:
-        specs.append(("mask_out", (n_slots, cap, cbp), (1, ta, tb),
-                      lambda s, i, j: (s, i, j)))
+        specs.append(("mask_out", (n_slots, cap, cbp), (None, ta, tb),
+                       lambda s, i, j: (s, i, j), 32))      # int8
     else:
-        specs += [
-            ("a_out", (n_slots, max_new), (1, max_new),
-             lambda s, i, j: (s, 0)),
-            ("b_out", (n_slots, max_new), (1, max_new),
-             lambda s, i, j: (s, 0)),
-            ("n_out", (n_slots, 1), (1, 1), lambda s, i, j: (s, 0)),
-        ]
+        r = out_rows(max_new)
+        for name in ("a_out", "b_out"):
+            specs.append((name, (n_slots, r, _LANE), (None, r, _LANE),
+                          lambda s, i, j: (s, 0, 0), 8))
+        specs.append(("n_out", (n_slots, 1, _LANE), (None, 1, _LANE),
+                      lambda s, i, j: (s, 0, 0), 8))
     return specs
 
 
@@ -195,40 +204,37 @@ def check_tiles_and_bounds(fast: bool = False) -> list[Finding]:
     """KC101/KC102/KC103 over the reachable lattice for the compat
     kernels, plus the fixed-tile segment_reduce / embedding_bag grids."""
     from repro.kernels.compat_join.kernel import (
-        _LANE, _SUBLANE, _ceil_to, choose_tiles)
+        _ceil_to, choose_tiles, mask_tile_a)
 
     findings: list[Finding] = []
     caps = CAPS_FAST if fast else CAPS_FULL
 
-    # --- compat_join: full choose_tiles lattice ---
+    # --- compat_join: full choose_tiles lattice, both kernels ---
     for ca, cb in itertools.product(caps, caps):
         ta, tb = choose_tiles(ca, cb)
-        cap, cbp = _ceil_to(ca, ta), _ceil_to(cb, tb)
         sym = f"choose_tiles({ca},{cb})"
-        if ta % _SUBLANE or tb % _LANE:
-            findings.append(_finding(
-                "KC102", ERROR, sym,
-                f"tile ({ta},{tb}) not ({_SUBLANE},{_LANE})-aligned"))
-        if cap % ta or cbp % tb or cap // ta < 1 or cbp // tb < 1:
-            findings.append(_finding(
-                "KC101", ERROR, sym,
-                f"padded caps ({cap},{cbp}) not exact multiples of "
-                f"tiles ({ta},{tb}) or empty grid"))
-            continue
         widths = (2, 1, 1, 1)
-        grid = (cap // ta, cbp // tb)
-        bad = _bounds_ok(grid, _compat_specs(cap, cbp, ta, tb, widths))
-        bad += _bounds_ok(grid, _compat_specs(cap, cbp, ta, tb, widths,
-                                              max_new=MAX_NEW[0]))
-        for n_slots, flags in itertools.product(
-                SLOTS if not fast else SLOTS[:2],
-                FLAG_SETS if not fast else FLAG_SETS[:2]):
-            g3 = (n_slots,) + grid
-            bad += _bounds_ok(g3, _compat_specs_batched(
-                n_slots, cap, cbp, ta, tb, widths, flags))
-            bad += _bounds_ok(g3, _compat_specs_batched(
-                n_slots, cap, cbp, ta, tb, widths, flags,
-                max_new=MAX_NEW[0]))
+        bad, refused = [], []
+        for max_new, ta_k in ((None, mask_tile_a(ta)), (MAX_NEW[0], ta)):
+            cap, cbp = _ceil_to(ca, ta_k), _ceil_to(cb, tb)
+            if cap % ta_k or cbp % tb or cap // ta_k < 1 or cbp // tb < 1:
+                findings.append(_finding(
+                    "KC101", ERROR, sym,
+                    f"padded caps ({cap},{cbp}) not exact multiples of "
+                    f"tiles ({ta_k},{tb}) or empty grid"))
+                continue
+            grid = (cap // ta_k, cbp // tb)
+            for n_slots, flags in itertools.product(
+                    SLOTS if not fast else SLOTS[:2],
+                    FLAG_SETS if not fast else FLAG_SETS[:2]):
+                specs = _compat_specs(n_slots, cap, cbp, ta_k, tb, widths,
+                                      *flags, max_new=max_new)
+                bad += _bounds_ok((n_slots,) + grid,
+                                  [sp[:4] for sp in specs])
+                refused += [f for sp in specs
+                            if (f := block_shape_finding(
+                                f"{sym}.{sp[0]}", sp[1], sp[2], sp[4]))]
+        findings += refused[:3]
         for name, point, ax, i, b, dim in bad[:3]:
             findings.append(_finding(
                 "KC103", ERROR, sym,
@@ -275,14 +281,16 @@ def check_tiles_and_bounds(fast: bool = False) -> list[Finding]:
 
 
 # --------------------------------------------------------------------- #
-# KC104: SMEM cursor interval proof
+# KC104: pair-output cursor interval proof
 # --------------------------------------------------------------------- #
 _CLAMP_EXPR = "jnp.minimum(n_tile, jnp.maximum(max_new - base, 0))"
 
 
 def check_smem_cursor(fast: bool = False) -> list[Finding]:
-    """Prove the pairs kernels' emit loop never writes at or beyond
-    ``max_new``, for any cursor value the grid can produce."""
+    """Prove the pairs kernel's emit loop never writes at or beyond
+    ``max_new``, for any cursor value the grid can produce, and that
+    every write's ``(row, lane) = divmod(p, 128)`` lands inside the
+    ``[out_rows(max_new), 128]`` output block."""
     import repro.kernels.compat_join.kernel as K
     findings: list[Finding] = []
 
@@ -300,6 +308,7 @@ def check_smem_cursor(fast: bool = False) -> list[Finding]:
         cap, cbp = K._ceil_to(ca, ta), K._ceil_to(cb, tb)
         n_tile_max = ta * tb
         for max_new in MAX_NEW:
+            rows = K.out_rows(max_new)
             # cursor extremes: 0, around the clamp knee, and the
             # absolute maximum (every pair of every tile matched)
             bases = {0, max(0, max_new - 1), max_new, max_new + 1,
@@ -307,14 +316,16 @@ def check_smem_cursor(fast: bool = False) -> list[Finding]:
             for base in bases:
                 for n_tile in (0, 1, n_tile_max):
                     n_emit = min(n_tile, max(max_new - base, 0))
-                    if n_emit > 0 and base + n_emit - 1 >= max_new:
+                    last = base + n_emit - 1
+                    if n_emit > 0 and (last >= max_new
+                                       or last // K._LANE >= rows):
                         findings.append(_finding(
                             "KC104", ERROR,
                             f"compat_join_pairs(ca={ca},cb={cb},"
                             f"max_new={max_new})",
                             f"cursor write base={base} k={n_emit - 1} "
-                            f"reaches index {base + n_emit - 1} >= "
-                            f"max_new={max_new}"))
+                            f"reaches index {last} (max_new={max_new}, "
+                            f"{rows} output rows)"))
     return findings
 
 

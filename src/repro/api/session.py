@@ -240,7 +240,7 @@ class StreamSession:
         level_capacity: int = 2048,
         l0_capacity: int = 2048,
         max_new: int = 512,
-        backend: str = J.JoinBackend.REF,
+        backend: str | None = None,
         max_out: int | None = None,
         ckpt_dir: str | None = None,
         keep_checkpoints: int = 8,

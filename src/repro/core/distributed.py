@@ -20,10 +20,6 @@ import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import join as J
-from repro.core.compat import (
-    shard_map as _shard_map,
-    shard_map_compat_kwargs as _shard_map_compat_kwargs,
-)
 from repro.core.engine import build_tick
 from repro.core.plan import ExecutionPlan
 from repro.core.state import EngineState, init_state
@@ -43,7 +39,7 @@ def build_sharded_tick(
     plan: ExecutionPlan,
     mesh: Mesh,
     axes=("data",),
-    backend: str = J.JoinBackend.REF,
+    backend: str | None = None,
     extract_matches: bool = False,
     prefix_depth: int = 0,
 ):
@@ -96,12 +92,12 @@ def build_sharded_tick(
         in_specs = in_specs + (NodeView(P(), P(), P(), P(), P()),)
 
     tick = jax.jit(
-        _shard_map(
+        jax.shard_map(
             inner,
             mesh=mesh,
             in_specs=in_specs,
             out_specs=(specs, out_res_specs),
-            **_shard_map_compat_kwargs(),
+            check_vma=False,
         )
     )
 

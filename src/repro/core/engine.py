@@ -158,7 +158,7 @@ def edge_match_mask(batch: EdgeBatch, esl, edl, eel) -> jnp.ndarray:
 
 def build_tick_body(
     plan: ExecutionPlan,
-    backend: str = J.JoinBackend.REF,
+    backend: str | None = None,
     extract_matches: bool = True,
     max_out: int | None = None,
     axis_name: str | None = None,
@@ -515,7 +515,7 @@ def build_tick_body(
 
 def build_tick(
     plan: ExecutionPlan,
-    backend: str = J.JoinBackend.REF,
+    backend: str | None = None,
     extract_matches: bool = True,
     max_out: int | None = None,
     axis_name: str | None = None,

@@ -4,7 +4,8 @@
 incoming edge is joined against expansion-list items, and TC-subquery
 deltas are joined against the global list.  The pure-jnp implementation
 here is the reference; ``repro.kernels.compat_join`` provides the Pallas
-TPU kernel with identical semantics (selected via ``JoinBackend``).
+TPU kernel with identical semantics (selected via ``JoinBackend``; the
+platform chooses, see ``resolve_backend``).
 
 Semantics of one (a, b) pair:
   * vertex slots:  rel[i, j]  => bind_a[a, i] == bind_b[b, j]
@@ -17,6 +18,7 @@ Semantics of one (a, b) pair:
 from __future__ import annotations
 
 import numpy as np
+import jax
 import jax.numpy as jnp
 
 
@@ -82,9 +84,35 @@ class JoinBackend:
     PALLAS_INTERPRET = "pallas_interpret"  # kernel body interpreted on CPU
 
 
+_BACKENDS = (JoinBackend.REF, JoinBackend.PALLAS, JoinBackend.PALLAS_INTERPRET)
+
+
+def resolve_backend(backend: str | None = None) -> str:
+    """The join backend to serve with: the platform picks it.
+
+    ``None`` resolves to ``PALLAS`` (the compiled kernels) when the
+    process's first device is a TPU and to ``REF`` otherwise.  An
+    explicit ``REF`` / ``PALLAS_INTERPRET`` is honored (tests pin them);
+    an explicit ``PALLAS`` off a TPU raises — the compiled kernels run
+    nowhere else, and no path falls back silently.
+    """
+    platform = jax.devices()[0].platform
+    if backend is None:
+        return JoinBackend.PALLAS if platform == "tpu" else JoinBackend.REF
+    if backend not in _BACKENDS:
+        raise ValueError(f"unknown join backend: {backend!r}")
+    if backend == JoinBackend.PALLAS and platform != "tpu":
+        raise ValueError(
+            f"join backend {backend!r} needs a TPU (platform is "
+            f"{platform!r}); pass None to let the platform choose")
+    return backend
+
+
 def compat_mask(bind_a, ets_a, valid_a, bind_b, ets_b, valid_b, rel, trel,
                 window: int | None = None,
-                backend: str = JoinBackend.REF) -> jnp.ndarray:
+                backend: str | None = None) -> jnp.ndarray:
+    if backend is None:
+        backend = resolve_backend()
     if backend == JoinBackend.REF:
         return compat_mask_ref(
             bind_a, ets_a, valid_a, bind_b, ets_b, valid_b, rel, trel, window)
@@ -96,7 +124,7 @@ def compat_mask(bind_a, ets_a, valid_a, bind_b, ets_b, valid_b, rel, trel,
 
 def join_pairs(bind_a, ets_a, valid_a, bind_b, ets_b, valid_b, rel, trel,
                max_new: int, window: int | None = None,
-               backend: str = JoinBackend.REF):
+               backend: str | None = None):
     """Fused compatibility join + pair extraction (the engine's hot path).
 
     Returns ``(a_idx, b_idx, pair_valid, n_dropped)`` — the contract of
@@ -107,8 +135,11 @@ def join_pairs(bind_a, ets_a, valid_a, bind_b, ets_b, valid_b, rel, trel,
     pairs on-chip and never materializes the [CA, CB] mask in HBM; the
     kernel emits pairs in tile order, so cross-backend equality is on
     the pair SET (and the exact ``n_dropped``), with a backend-defined
-    keep-subset in the overflow case.
+    keep-subset in the overflow case.  ``backend=None`` resolves by
+    platform (``resolve_backend``).
     """
+    if backend is None:
+        backend = resolve_backend()
     if backend == JoinBackend.REF:
         mask = compat_mask_ref(
             bind_a, ets_a, valid_a, bind_b, ets_b, valid_b, rel, trel, window)

@@ -30,22 +30,24 @@ batch.  This module fuses N queries into one jit-able tick:
     a changing query population at a fixed compile budget.
 
 Backend note: both ticks accept the same ``backend`` as ``build_tick``
-(``JoinBackend.REF`` / ``PALLAS`` / ``PALLAS_INTERPRET``), and ALL
+(``JoinBackend.REF`` / ``PALLAS`` / ``PALLAS_INTERPRET``; ``None`` lets
+the platform choose — ``repro.core.join.resolve_backend``), and ALL
 variants — including the slot tick's traced per-slot windows — are
 served by every backend.  The Pallas kernels take ``window`` as a
 scalar-prefetch input (not a specialization constant), and the vmapped
 slot-group joins batch into ONE stacked 3-D-grid ``pallas_call`` per
 join (slot, A-tile, B-tile) via the custom-vmap rule in
 ``repro.kernels.compat_join.ops`` — no per-slot dispatch, and
-registering a query never recompiles.  Parity with REF is enforced by
-tests/test_slot_tick_pallas.py in interpret mode (CI is CPU-only);
-the compiled ``PALLAS`` path — in particular the fused pair-emission
-loop — has not yet been validated on real TPU hardware (see
-ROADMAP.md), so prefer ``PALLAS_INTERPRET``/``REF`` until it has.
+registering a query never recompiles.  Parity with REF is enforced on
+the CPU by tests/test_slot_tick_pallas.py in interpret mode, the
+compiled slot tick is compiled for a described TPU v5e by
+tests/test_chip_compile.py, and ``chip_smoke.py`` checks on a TPU v5e
+that the served PALLAS tick equals REF and the oracle.
 """
 
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple, Sequence
 
 import numpy as np
@@ -102,7 +104,7 @@ def reset_query(mstate: MultiEngineState, plans: Sequence[ExecutionPlan],
 
 def build_multi_tick(
     plans: Sequence[ExecutionPlan],
-    backend: str = J.JoinBackend.REF,
+    backend: str | None = None,
     extract_matches: bool = True,
     max_out: int | None = None,
 ):
@@ -191,6 +193,30 @@ def init_slot_state(template_plan: ExecutionPlan, n_slots: int,
     )
 
 
+def _put_row(full, row, k):
+    """``full[k] = row``, keeping ``full``'s (possibly mesh-) sharding."""
+    return full.at[k].set(row, out_sharding=jax.typeof(full).sharding)
+
+
+@functools.partial(jax.jit, donate_argnums=(0,))
+def _arm_slot(sstate: SlotState, k, engine: EngineState,
+              params: SlotParams) -> SlotState:
+    return SlotState(
+        engines=jax.tree.map(lambda f, r: _put_row(f, r, k),
+                             sstate.engines, engine),
+        params=jax.tree.map(lambda f, r: _put_row(f, r, k),
+                            sstate.params, params))
+
+
+@functools.partial(jax.jit, donate_argnums=(0,))
+def _disarm_slot(sstate: SlotState, k, engine: EngineState) -> SlotState:
+    p = sstate.params
+    return SlotState(
+        engines=jax.tree.map(lambda f, r: _put_row(f, r, k),
+                             sstate.engines, engine),
+        params=p._replace(active=_put_row(p.active, False, k)))
+
+
 def write_slot(sstate: SlotState, template_plan: ExecutionPlan, k: int,
                plan: ExecutionPlan,
                empty: EngineState | None = None) -> SlotState:
@@ -198,39 +224,33 @@ def write_slot(sstate: SlotState, template_plan: ExecutionPlan, k: int,
 
     ``plan`` must share ``template_plan``'s structural signature
     (``repro.core.registry.plan_signature``) — the caller (service)
-    guarantees this by construction.  Pure data writes: no recompile.
-    Pass a cached ``empty = init_state(template_plan)`` to avoid
-    re-materializing the full-capacity empty tables per churn event.
+    guarantees this by construction.  Pure data writes: no recompile
+    (``k`` is traced).  Pass a cached ``empty = init_state(template_plan)``
+    to avoid re-materializing the full-capacity empty tables per churn
+    event, or another slot's engine rows to move a tenant with its state.
+
+    The write runs under ``jit`` and DONATES ``sstate`` (callers must
+    treat it as consumed); the result keeps ``sstate``'s sharding, so a
+    replica-sharded group (``repro.runtime.mesh``) stays sharded.
     """
     if empty is None:
         empty = init_state(template_plan)
-    p = sstate.params
-    return SlotState(
-        engines=jax.tree.map(
-            lambda full, e: full.at[k].set(e),
-            sstate.engines, empty),
-        params=SlotParams(
-            esl=p.esl.at[k].set(jnp.asarray(plan.edge_src_label)),
-            edl=p.edl.at[k].set(jnp.asarray(plan.edge_dst_label)),
-            eel=p.eel.at[k].set(jnp.asarray(plan.edge_edge_label)),
-            window=p.window.at[k].set(plan.window),
-            active=p.active.at[k].set(True),
-        ),
-    )
+    params = SlotParams(
+        esl=jnp.asarray(plan.edge_src_label, I32),
+        edl=jnp.asarray(plan.edge_dst_label, I32),
+        eel=jnp.asarray(plan.edge_edge_label, I32),
+        window=jnp.asarray(plan.window, I32),
+        active=jnp.asarray(True))
+    return _arm_slot(sstate, k, empty, params)
 
 
 def clear_slot(sstate: SlotState, template_plan: ExecutionPlan, k: int,
                empty: EngineState | None = None) -> SlotState:
-    """Disarm slot ``k`` (unregister): deactivate + drop its tables."""
+    """Disarm slot ``k`` (unregister): deactivate + drop its tables.
+    Donates ``sstate`` like ``write_slot``."""
     if empty is None:
         empty = init_state(template_plan)
-    return SlotState(
-        engines=jax.tree.map(
-            lambda full, e: full.at[k].set(e),
-            sstate.engines, empty),
-        params=sstate.params._replace(
-            active=sstate.params.active.at[k].set(False)),
-    )
+    return _disarm_slot(sstate, k, empty)
 
 
 def read_slot(sstate: SlotState, k: int) -> EngineState:
@@ -240,7 +260,7 @@ def read_slot(sstate: SlotState, k: int) -> EngineState:
 
 def build_slot_tick(
     template_plan: ExecutionPlan,
-    backend: str = J.JoinBackend.REF,
+    backend: str | None = None,
     extract_matches: bool = True,
     max_out: int | None = None,
     prefix_depth: int = 0,
@@ -389,7 +409,7 @@ class SlotTickCache:
     def get(
         self,
         template_plan: ExecutionPlan,
-        backend: str = J.JoinBackend.REF,
+        backend: str | None = None,
         extract_matches: bool = True,
         max_out: int | None = None,
         jit: bool = True,
@@ -413,7 +433,7 @@ class SlotTickCache:
         template_plan: ExecutionPlan,
         mesh,                                    # jax.sharding.Mesh
         slots_per_replica: int,
-        backend: str = J.JoinBackend.REF,
+        backend: str | None = None,
         extract_matches: bool = True,
         max_out: int | None = None,
         donate: bool = True,
@@ -445,7 +465,7 @@ class SlotTickCache:
     def get_node(
         self,
         spec,                                   # repro.core.share.NodeSpec
-        backend: str = J.JoinBackend.REF,
+        backend: str | None = None,
         jit: bool = True,
         donate: bool = False,
     ):

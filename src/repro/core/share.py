@@ -180,7 +180,7 @@ def init_node_state(spec: NodeSpec) -> NodeState:
                      n_overflow=jnp.zeros((), I32))
 
 
-def build_node_tick(spec: NodeSpec, backend: str = J.JoinBackend.REF):
+def build_node_tick(spec: NodeSpec, backend: str | None = None):
     """Compile the per-tick advance of one prefix node.
 
     Root:   ``tick(state, batch, esl, edl, eel, window, watermark=None)``
@@ -307,10 +307,10 @@ class SharedPrefixForest:
     tick.  Owned by one ``ContinuousSearchService``; node ticks come from
     the (usually process-wide) ``SlotTickCache``."""
 
-    def __init__(self, tick_cache, backend: str = J.JoinBackend.REF,
+    def __init__(self, tick_cache, backend: str | None = None,
                  jit: bool = True, donate: bool = False):
         self.tick_cache = tick_cache
-        self.backend = backend
+        self.backend = J.resolve_backend(backend)
         self._jit = jit
         self.donate = donate
         self._by_key: dict[tuple, PrefixNode] = {}   # (sig, epoch) -> node

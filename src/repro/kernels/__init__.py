@@ -11,6 +11,8 @@ embedding_bag   RecSys: fused multi-hot gather + segment-sum over huge
 
 Each kernel ships: ``kernel.py`` (pl.pallas_call + BlockSpec tiling),
 ``ops.py`` (jit'd public wrapper with padding + interpret switch) and
-``ref.py`` (pure-jnp oracle).  CPU CI validates via interpret=True; the
-compiled path targets TPU v5e (VMEM tiles sized in kernel.py).
+``ref.py`` (pure-jnp oracle).  On the CPU the compat_join kernels run
+in interpret mode (interpret=True) against their oracle; the compiled
+compat_join path runs on TPU v5e, where the platform selects it
+(``repro.core.join.resolve_backend``) and ``chip_smoke.py`` checks it.
 """

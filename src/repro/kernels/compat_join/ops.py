@@ -7,18 +7,19 @@ Responsibilities:
   spec (lru-cached by content), so repeated joins with the same spec
   reuse the *identical* static kernel key instead of rebuilding nested
   tuples per tick.
-* **Adaptive tiling + padding** — tile sizes come from
-  ``kernel.choose_tiles`` (shape-derived), and the capacity axes are
-  padded to tile multiples with ``valid=0`` rows that never match.
+* **Packing, tiling + padding** — each side's (bind, ets, valid) is
+  packed into one transposed int32 operand (table rows on lanes; see
+  ``kernel``), tile sizes come from ``kernel.choose_tiles``
+  (shape-derived), and the capacity axes are padded to tile multiples
+  with ``valid=0`` rows that never match.
 * **Batched (vmapped) dispatch** — each op is wrapped in
-  ``jax.custom_batching.custom_vmap``: an unvmapped call lowers to the
-  2-D-grid kernel, while a vmapped call (the slot ticks of
-  ``repro.core.multi``) lowers to ONE stacked 3-D-grid kernel over
-  ``(slot, A-tile, B-tile)`` — one ``pallas_call`` per join for the
-  whole slot group, with per-slot traced windows.  Operands shared
-  across slots (e.g. the slot tick's stream-edge side) are NOT
-  broadcast: they stay 2-D and the kernel's index_map ignores the slot
-  grid dim, so the shared bytes are read once.
+  ``jax.custom_batching.custom_vmap``: an unvmapped call is the
+  one-slot case of the kernel's ``(slot, A-tile, B-tile)`` grid, while
+  a vmapped call (the slot ticks of ``repro.core.multi``) lowers to ONE
+  such kernel for the whole slot group — one ``pallas_call`` per join,
+  with per-slot traced windows.  A side shared across slots (e.g. the
+  slot tick's stream edges in a prefix-node join) is NOT broadcast: it
+  stays 2-D and the kernel's index_map ignores the slot grid dim.
 * **Traced window** — ``window`` is passed to the kernel as a
   scalar-prefetch input; changing it (or any slot's window) never
   recompiles.  Only *whether* a window predicate exists is static.
@@ -72,18 +73,19 @@ def normalize_spec(rel, trel):
 
 
 # --------------------------------------------------------------------- #
-# Padding helpers.
+# Packing + padding.
 # --------------------------------------------------------------------- #
-def _pad_to(x, n, axis=0):
+_ceil_to = K._ceil_to
+_UNBATCHED = (False,) * 7       # in_batched of an unvmapped call
+
+
+def _pad_to(x, n, axis):
     pad = n - x.shape[axis]
     if pad == 0:
         return x
     widths = [(0, 0)] * x.ndim
     widths[axis] = (0, pad)
     return jnp.pad(x, widths)
-
-
-_ceil_to = K._ceil_to
 
 
 def _as_window(window):
@@ -93,35 +95,45 @@ def _as_window(window):
     return jnp.asarray(window, jnp.int32).reshape(())
 
 
-def _prep_tables(bind, ets, valid, cap, axis):
-    return (_pad_to(bind.astype(jnp.int32), cap, axis),
-            _pad_to(ets.astype(jnp.int32), cap, axis),
-            _pad_to(valid.astype(jnp.int32), cap, axis))
+def _pack_side(bind, ets, valid, flags, n_slots, cap):
+    """One packed, transposed int32 operand ``[S?, nv + ne + 1, cap]``,
+    padded with ``valid = 0`` rows.
 
-
-def _prep_stacked(args, in_batched, axis_size):
-    """Pad/cast the six table args + window for the stacked kernel.
-
-    Per-slot inputs pad along their row axis (1); inputs shared across
-    slots stay 2-D — the kernel reads them once via an index_map that
-    ignores the slot grid dim instead of broadcasting S× through HBM.
-    Only the (tiny) window is materialized per-slot.
+    The side carries the slot axis if any of its three parts does; the
+    parts that don't are broadcast to it (only the narrow columns of a
+    per-slot side, never a whole shared table).
     """
-    *tables, window = args
-    flags = tuple(bool(b) for b in in_batched[:6])
-    if not in_batched[6]:
-        window = jnp.broadcast_to(window, (axis_size,))
-    ca = tables[0].shape[-2]
-    cb = tables[3].shape[-2]
+    parts = [bind, ets, valid[..., None]]
+    batched = any(flags)
+    if batched:
+        parts = [x if f else jnp.broadcast_to(x, (n_slots,) + x.shape)
+                 for x, f in zip(parts, flags)]
+    packed = jnp.concatenate([x.astype(jnp.int32) for x in parts], axis=-1)
+    return _pad_to(jnp.swapaxes(packed, -1, -2), cap, axis=-1), batched
+
+
+def _prep(args, in_batched, n_slots, mask):
+    """Window + packed operands + static kernel kwargs for one call.
+
+    An unbatched call is the ``n_slots = 1`` stacked call with both
+    sides shared.
+    """
+    bind_a, ets_a, valid_a, bind_b, ets_b, valid_b, window = args
+    ca, cb = bind_a.shape[-2], bind_b.shape[-2]
     ta, tb = K.choose_tiles(ca, cb)
+    if mask:
+        ta = K.mask_tile_a(ta)
     cap, cbp = _ceil_to(max(ca, 1), ta), _ceil_to(max(cb, 1), tb)
-    padded = [
-        _pad_to(x.astype(jnp.int32), n, axis=1 if f else 0)
-        for x, f, n in zip(tables, flags,
-                           (cap, cap, cap, cbp, cbp, cbp))
-    ]
-    return (window.reshape(axis_size), padded, flags,
-            dict(tile_a=ta, tile_b=tb), ca, cb)
+    a, a_batched = _pack_side(bind_a, ets_a, valid_a, in_batched[0:3],
+                              n_slots, cap)
+    b, b_batched = _pack_side(bind_b, ets_b, valid_b, in_batched[3:6],
+                              n_slots, cbp)
+    window = jnp.broadcast_to(window, (n_slots,))
+    kw = dict(widths=(bind_a.shape[-1], ets_a.shape[-1],
+                      bind_b.shape[-1], ets_b.shape[-1]),
+              tile_a=ta, tile_b=tb, n_slots=n_slots,
+              a_batched=a_batched, b_batched=b_batched)
+    return window, a, b, kw, ca, cb
 
 
 # --------------------------------------------------------------------- #
@@ -129,28 +141,20 @@ def _prep_stacked(args, in_batched, axis_size):
 # --------------------------------------------------------------------- #
 @functools.lru_cache(maxsize=None)
 def _mask_op(rel, trel, has_window, interpret):
-    @custom_vmap
-    def op(bind_a, ets_a, valid_a, bind_b, ets_b, valid_b, window):
-        ca, cb = bind_a.shape[0], bind_b.shape[0]
-        ta, tb = K.choose_tiles(ca, cb)
-        cap, cbp = _ceil_to(max(ca, 1), ta), _ceil_to(max(cb, 1), tb)
-        a = _prep_tables(bind_a, ets_a, valid_a, cap, 0)
-        b = _prep_tables(bind_b, ets_b, valid_b, cbp, 0)
+    def run(args, in_batched, n_slots):
+        window, a, b, kw, ca, cb = _prep(args, in_batched, n_slots, True)
         out = K.compat_mask_kernel(
-            window.reshape(1), *a, *b,
-            rel=rel, trel=trel, has_window=has_window,
-            tile_a=ta, tile_b=tb, interpret=interpret)
-        return out[:ca, :cb].astype(jnp.bool_)
+            window, a, b, rel=rel, trel=trel, has_window=has_window,
+            interpret=interpret, **kw)
+        return out[:, :ca, :cb].astype(jnp.bool_)
+
+    @custom_vmap
+    def op(*args):
+        return run(args, _UNBATCHED, 1)[0]
 
     @op.def_vmap
     def _rule(axis_size, in_batched, *args):
-        window, padded, flags, tiles, ca, cb = _prep_stacked(
-            args, in_batched, axis_size)
-        out = K.compat_mask_kernel_batched(
-            window, *padded,
-            rel=rel, trel=trel, has_window=has_window, **tiles,
-            batched=flags, n_slots=axis_size, interpret=interpret)
-        return out[:, :ca, :cb].astype(jnp.bool_), True
+        return run(args, in_batched, axis_size), True
 
     return op
 
@@ -175,29 +179,22 @@ def compat_mask(bind_a, ets_a, valid_a, bind_b, ets_b, valid_b, rel, trel,
 # --------------------------------------------------------------------- #
 @functools.lru_cache(maxsize=None)
 def _pairs_op(rel, trel, max_new, has_window, interpret):
-    @custom_vmap
-    def op(bind_a, ets_a, valid_a, bind_b, ets_b, valid_b, window):
-        ca, cb = bind_a.shape[0], bind_b.shape[0]
-        ta, tb = K.choose_tiles(ca, cb)
-        cap, cbp = _ceil_to(max(ca, 1), ta), _ceil_to(max(cb, 1), tb)
-        a = _prep_tables(bind_a, ets_a, valid_a, cap, 0)
-        b = _prep_tables(bind_b, ets_b, valid_b, cbp, 0)
+    def run(args, in_batched, n_slots):
+        window, a, b, kw, _, _ = _prep(args, in_batched, n_slots, False)
         a_idx, b_idx, n_total = K.compat_join_pairs_kernel(
-            window.reshape(1), *a, *b,
-            rel=rel, trel=trel, has_window=has_window,
-            tile_a=ta, tile_b=tb, max_new=max_new, interpret=interpret)
-        return a_idx, b_idx, n_total[0]
+            window, a, b, rel=rel, trel=trel, has_window=has_window,
+            max_new=max_new, interpret=interpret, **kw)
+        return (a_idx.reshape(n_slots, -1)[:, :max_new],
+                b_idx.reshape(n_slots, -1)[:, :max_new],
+                n_total[:, 0, 0])
+
+    @custom_vmap
+    def op(*args):
+        return tuple(x[0] for x in run(args, _UNBATCHED, 1))
 
     @op.def_vmap
     def _rule(axis_size, in_batched, *args):
-        window, padded, flags, tiles, ca, cb = _prep_stacked(
-            args, in_batched, axis_size)
-        a_idx, b_idx, n_total = K.compat_join_pairs_kernel_batched(
-            window, *padded,
-            rel=rel, trel=trel, has_window=has_window, **tiles,
-            max_new=max_new, batched=flags, n_slots=axis_size,
-            interpret=interpret)
-        return (a_idx, b_idx, n_total[:, 0]), (True, True, True)
+        return run(args, in_batched, axis_size), (True, True, True)
 
     return op
 

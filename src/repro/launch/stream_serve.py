@@ -28,7 +28,6 @@ from repro.checkpoint import (
     latest_step,
     load_manifest,
 )
-from repro.core import join as J
 from repro.core.plan import ExecutionPlan
 from repro.core.registry import plan_decomposition
 from repro.runtime.service import ContinuousSearchService
@@ -42,9 +41,11 @@ class StreamServer:
                  extract_matches: bool | None = None,
                  backend: str | None = None,
                  tick_cache=None):
-        """``backend`` / ``extract_matches`` left unset mean: use the
-        checkpointed values when restoring (REF / True when starting
-        fresh) — passing them explicitly overrides either way."""
+        """``backend`` left unset lets the platform choose
+        (``repro.core.join.resolve_backend``), fresh or restored;
+        ``extract_matches`` left unset means the checkpointed value when
+        restoring (True when starting fresh).  Passing either explicitly
+        overrides."""
         lv = plan.subqueries[0].levels[0]
         l0_cap = plan.l0_joins[0].capacity if plan.l0_joins else lv.capacity
         self._coalescer = None       # AIMD state, persistent across ingests
@@ -101,7 +102,7 @@ class StreamServer:
                 level_capacity=lv.capacity,
                 l0_capacity=l0_cap,
                 max_new=lv.max_new,
-                backend=J.JoinBackend.REF if backend is None else backend,
+                backend=backend,
                 extract_matches=(True if extract_matches is None
                                  else extract_matches),
                 ckpt_dir=ckpt_dir,

@@ -64,20 +64,21 @@ from repro.checkpoint import (
     validate_checkpoint,
 )
 from repro.core import join as J
-from repro.core.compat import (
-    shard_map as _shard_map,
-    shard_map_compat_kwargs as _shard_map_compat_kwargs,
-)
 from repro.core.multi import (
     SlotTickCache,
     build_slot_tick,
     init_slot_state,
+    read_slot,
     write_slot,
 )
 from repro.core.plan import ExecutionPlan
 from repro.core.query import QueryGraph
 from repro.core.state import init_state
-from repro.runtime.service import ContinuousSearchService, _Group
+from repro.runtime.service import (
+    ContinuousSearchService,
+    _Group,
+    _serving_config,
+)
 
 I32 = jnp.int32
 
@@ -97,7 +98,7 @@ class MeshTickStats(NamedTuple):
 def build_mesh_slot_tick(
     template_plan: ExecutionPlan,
     mesh,                                   # jax.sharding.Mesh, 1-D "replica"
-    backend: str = J.JoinBackend.REF,
+    backend: str | None = None,
     extract_matches: bool = True,
     max_out: int | None = None,
     donate: bool = True,
@@ -163,9 +164,8 @@ def build_mesh_slot_tick(
         out_specs = (state_spec, state_spec,
                      MeshTickStats(repl, repl, repl))
         return jax.jit(
-            _shard_map(fn, mesh=mesh, in_specs=in_specs,
-                       out_specs=out_specs,
-                       **_shard_map_compat_kwargs()),
+            jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                          out_specs=out_specs, check_vma=False),
             **donate_kw)
 
     def _get(has_wm: bool):
@@ -305,9 +305,13 @@ class ShardedSearchService(ContinuousSearchService):
         self.n_replicas = int(n_replicas)
         self.slots_per_replica = int(slots_per_replica)
         self.placement = _resolve_placement(placement)
+        # Auto axis: the slot axis is only *placed* on the mesh (tenants
+        # are independent), so host-side per-slot reads and the slot
+        # writes keep working on sharded state without per-op shardings.
         self.mesh = jax.make_mesh(
             (self.n_replicas,), ("replica",),
-            devices=devices[:self.n_replicas])
+            devices=devices[:self.n_replicas],
+            axis_types=(jax.sharding.AxisType.Auto,))
         self.mesh_stats: dict[int, MeshTickStats] = {}  # gid -> last tick
         super().__init__(
             slots_per_group=self.n_replicas * self.slots_per_replica, **kw)
@@ -542,7 +546,7 @@ class ShardedSearchService(ContinuousSearchService):
     def _restore_reshard(cls, ckpt_dir, step, man, tick_cache, overrides,
                          n_replicas):
         """Restore onto a mesh of a different size: re-place and splice."""
-        config = dict(man["config"])
+        config = _serving_config(man)
         mesh_cfg = dict(config.pop("mesh"))
         mesh_cfg["n_replicas"] = n_replicas
         svc = cls(ckpt_dir=ckpt_dir, tick_cache=tick_cache,
@@ -592,12 +596,7 @@ class ShardedSearchService(ContinuousSearchService):
                 group, k2 = svc._place(gs, rq.plan, leaf, rq.signature)
                 group.sstate = write_slot(
                     group.sstate, group.template, k2, rq.plan,
-                    empty=group.empty)
-                group.sstate = group.sstate._replace(
-                    engines=jax.tree.map(
-                        lambda full, oldarr, k2=k2, k=k:
-                            full.at[k2].set(oldarr[k]),
-                        group.sstate.engines, old.engines))
+                    empty=read_slot(old, k))
                 group.qids[k2] = qid
                 svc._location[qid] = (group, k2)
                 if leaf is not None:

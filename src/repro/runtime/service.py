@@ -71,12 +71,12 @@ server misses nothing still inside the window: the differential test
 (tests/test_service_restore.py) proves crash + restore reports exactly
 the same match set as an uninterrupted run.
 
-``backend`` selects the compatibility-join implementation for every
-group's slot tick: ``JoinBackend.REF`` (pure jnp), ``PALLAS`` (fused
-TPU kernels), or ``PALLAS_INTERPRET`` (the kernels interpreted on CPU,
-for validation).  The compiled ``PALLAS`` path is interpret-parity-
-tested only (CI has no TPU); validate on hardware before serving with
-it (ROADMAP.md).
+The compatibility-join implementation of every group's slot tick is
+chosen by the platform (``repro.core.join.resolve_backend``): the fused
+Pallas kernels (``PALLAS``) on a TPU, the pure-jnp reference (``REF``)
+elsewhere.  Tests may pin ``REF`` or ``PALLAS_INTERPRET`` (the kernels
+interpreted on the CPU); the compiled path is checked against REF on a
+TPU v5e by ``chip_smoke.py``.
 
 Example
 -------
@@ -135,6 +135,15 @@ from repro.core.state import EdgeBatch, EngineState, init_state, make_batch
 from repro.obs import MetricsRegistry, Tracer
 from repro.runtime.straggler import TickCoalescer, quantize_pow2
 from repro.stream.generator import to_batches
+
+
+def _serving_config(man: dict) -> dict:
+    """A manifest's constructor config, minus what the restoring process
+    decides for itself: checkpoints from before the backend left the
+    manifest still carry one, and it must not pin the join kernels."""
+    config = dict(man["config"])
+    config.pop("backend", None)
+    return config
 
 
 class ServeInfo(NamedTuple):
@@ -196,7 +205,7 @@ class ContinuousSearchService:
         level_capacity: int = 2048,
         l0_capacity: int = 2048,
         max_new: int = 512,
-        backend: str = J.JoinBackend.REF,
+        backend: str | None = None,
         extract_matches: bool = True,
         max_out: int | None = None,
         jit: bool = True,
@@ -209,11 +218,8 @@ class ContinuousSearchService:
         obs: MetricsRegistry | None = None,
         tracer: Tracer | None = None,
     ):
-        if backend not in (J.JoinBackend.REF, J.JoinBackend.PALLAS,
-                           J.JoinBackend.PALLAS_INTERPRET):
-            raise ValueError(f"unknown join backend: {backend!r}")
         self.slots_per_group = slots_per_group
-        self.backend = backend
+        self.backend = J.resolve_backend(backend)
         self.extract_matches = extract_matches
         self.max_out = max_out
         self._jit = jit
@@ -232,7 +238,7 @@ class ContinuousSearchService:
         self._groups: dict[tuple, list[_Group]] = {}
         self._location: dict[int, tuple[_Group, int]] = {}
         self.forest = (SharedPrefixForest(
-            self.tick_cache, backend=backend, jit=jit,
+            self.tick_cache, backend=self.backend, jit=jit,
             donate=self.donate) if enable_sharing else None)
         self._prefix_of: dict[int, object] = {}   # qid -> leaf PrefixNode
         self._next_gid = 0
@@ -575,7 +581,7 @@ class ContinuousSearchService:
             chunk = edges[i:i + coalescer.batch]
             queue_depth = n - (i + len(chunk))
             lat_ms, tick_overflow, n_shared = self._tick_chunk(
-                chunk, on_match, totals)
+                chunk, on_match, totals, min_width=coalescer.min_batch)
             # overflow joins latency and queue depth as a throttle input:
             # dropped appends mean the tick was too big for the tables
             coalescer.record(lat_ms, queue_depth, tick_overflow)
@@ -597,9 +603,13 @@ class ContinuousSearchService:
         return totals
 
     def _tick_chunk(self, chunk: list, on_match, totals: dict,
-                    watermark=None) -> tuple[float, int, int]:
-        """One production tick over ``chunk`` (a DataEdge list): pow-2
-        padded batch, async group dispatch, ONE barrier, match delivery.
+                    watermark=None, min_width: int = 8
+                    ) -> tuple[float, int, int]:
+        """One production tick over ``chunk`` (a DataEdge list): batch
+        padded to a power of two of at least ``min_width`` (the
+        coalescer's floor, so a fixed batch size is one jit shape however
+        ragged the released chunks are), async group dispatch, ONE
+        barrier, match delivery (one host read per group).
         Updates ``totals``/counters in place; returns (barrier latency
         ms, tick overflow, shared-prefix node count).  Shared by
         ``serve_stream`` (arrival-order chunks, ``watermark=None``) and
@@ -610,7 +620,7 @@ class ContinuousSearchService:
             tr.next_tick()
         active = [g for g in self._iter_groups() if not g.idle]
         batch = make_batch(
-            **to_batches(chunk, quantize_pow2(len(chunk)))[0])
+            **to_batches(chunk, quantize_pow2(len(chunk), lo=min_width))[0])
         t0 = time.perf_counter()
         views, forest_nds = self._advance_forest(batch, watermark)
         if tr is None:
@@ -642,19 +652,25 @@ class ContinuousSearchService:
         tick_overflow = 0
         n_matches = 0
         for g, res in results:
+            # the group's [S] counters come back in one transfer; match
+            # rows only when a slot of this group has new matches
+            n_new_s, n_ov_s = jax.device_get(
+                (res.n_new_matches, res.n_overflow))
+            rows = None
             for k, qid in enumerate(g.qids):
                 if qid is None:
                     continue
-                r = jax.tree.map(lambda x, k=k: x[k], res)
-                n_new = int(r.n_new_matches)
-                tick_overflow += int(r.n_overflow)
+                n_new = int(n_new_s[k])
+                tick_overflow += int(n_ov_s[k])
                 n_matches += n_new
                 totals[qid] = totals.get(qid, 0) + n_new
                 if n_new and on_match is not None:
-                    valid = np.asarray(r.match_valid)
-                    on_match(qid,
-                             np.asarray(r.match_bindings)[valid],
-                             np.asarray(r.match_ets)[valid])
+                    if rows is None:
+                        rows = jax.device_get((res.match_bindings,
+                                               res.match_ets,
+                                               res.match_valid))
+                    mb, me, mv = (x[k] for x in rows)
+                    on_match(qid, mb[mv], me[mv])
         if tr is not None:
             tr.record("tick.deliver",
                       (time.perf_counter() - t_end) * 1e3,
@@ -789,7 +805,8 @@ class ContinuousSearchService:
             wm_in = jnp.asarray(
                 NO_WATERMARK if wm is None else wm, jnp.int32)
             lat_ms, tick_overflow, n_shared = self._tick_chunk(
-                chunk, on_match, totals, wm_in)
+                chunk, on_match, totals, wm_in,
+                min_width=coalescer.min_batch)
             if tr is not None:
                 # recorded after _tick_chunk so the spans carry this
                 # tick's correlation id (next_tick advances in there)
@@ -841,7 +858,6 @@ class ContinuousSearchService:
                 "level_capacity": self.registry.level_capacity,
                 "l0_capacity": self.registry.l0_capacity,
                 "max_new": self.registry.max_new,
-                "backend": self.backend,
                 "extract_matches": self.extract_matches,
                 "max_out": self.max_out,
                 "jit": self._jit,
@@ -981,10 +997,13 @@ class ContinuousSearchService:
         ``SlotTickCache``: a structure this process has already served
         restores with zero recompiles.
 
-        ``backend`` / ``extract_matches`` override the checkpointed
-        config (both are serving-behavior knobs, independent of the
-        persisted state layout); by default the checkpointed values are
-        kept.
+        ``extract_matches`` overrides the checkpointed config (a
+        serving-behavior knob, independent of the persisted state
+        layout).  The join ``backend`` is never read from a checkpoint:
+        the restoring platform resolves it (``resolve_backend``), so a
+        checkpoint written on one platform serves with the kernels of
+        another; an explicit ``backend`` is honored like the
+        constructor's.
         """
         candidates = ([step] if step is not None
                       else list(reversed(checkpoint_steps(ckpt_dir))))
@@ -1017,7 +1036,7 @@ class ContinuousSearchService:
         # full manifest; torn links raise CheckpointError so the restore
         # candidate loop falls back to an older step.
         man = load_resolved_manifest(ckpt_dir, step, "service")
-        config = dict(man["config"])
+        config = _serving_config(man)
         if "mesh" in config and not hasattr(cls, "_MESH_SERVICE"):
             # Checkpoint was written by a ShardedSearchService but
             # restore() was called on the base class: delegate.

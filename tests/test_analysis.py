@@ -69,11 +69,11 @@ def test_lint_obs_sites_census_and_clean_tree():
 
 
 def test_lint_recognizes_aliased_shard_map_roots(tmp_path):
-    """The compat shim imports ``shard_map as _shard_map``; functions
-    handed to the alias must still become traced roots (TRC-checked)
-    and be counted in the ``n_shard_map_roots`` census."""
+    """An aliased import ``shard_map as _shard_map``: functions handed
+    to the alias must still become traced roots (TRC-checked) and be
+    counted in the ``n_shard_map_roots`` census."""
     (tmp_path / "m.py").write_text(
-        "from repro.core.compat import shard_map as _shard_map\n"
+        "from jax import shard_map as _shard_map\n"
         "def serve(mesh):\n"
         "    def body(x):\n"
         "        return int(x) + 1\n"
@@ -109,7 +109,7 @@ def test_lint_tree_clean_at_error_severity():
 def test_kernel_contracts_prove_clean():
     findings, stats = KC.check_kernels(fast=True)
     assert [f.format() for f in findings] == []
-    assert stats["n_pallas_sites"] == 6
+    assert stats["n_pallas_sites"] == 4
 
 
 def test_bounds_checker_catches_non_divisible_blockspec():
@@ -118,6 +118,23 @@ def test_bounds_checker_catches_non_divisible_blockspec():
     assert bad and bad[0][0] == "x"
     # and a correct tiling proves clean
     assert KC._bounds_ok((2,), [("x", (128,), (64,), lambda i: (i,))]) == []
+
+
+@pytest.mark.parametrize("array_shape,block_shape", [
+    ((2048,), (256,)),          # 1-D int32 (tile,) block: layout refused
+    ((64, 2048), (1, 256)),     # per-slot (1, tile) block of a [S, C] array
+])
+def test_block_rule_rejects_shapes_the_compiler_refused(array_shape,
+                                                        block_shape):
+    """KC102 encodes the TPU compiler's block rule: the shapes the v5e
+    compiler refused for the compat_join kernels are findings, and the
+    packed layout the kernels use now proves clean."""
+    f = KC.block_shape_finding("x", array_shape, block_shape)
+    assert f is not None and f.rule == "KC102" and f.severity == ERROR
+    assert KC.block_shape_finding("x", (2048,), (2048,)) is None
+    assert KC.block_shape_finding("x", (64, 6, 16384), (None, 6, 256)) is None
+    assert KC.block_shape_finding("x", (64, 16384, 1024),
+                                  (None, 256, 256), sublane=32) is None
 
 
 def test_unmodeled_pallas_call_flagged(tmp_path):
@@ -272,7 +289,7 @@ def test_cli_green_on_tree_and_writes_report(tmp_path, capsys):
     assert doc["schema"] == "repro_analysis/v1"
     assert doc["findings_by_severity"]["error"] == 0
     assert doc["findings_by_severity"]["warning"] == 0
-    assert doc["stats"]["n_pallas_sites"] == 6
+    assert doc["stats"]["n_pallas_sites"] == 4
     assert doc["stats"]["n_plans_verified"] >= 10
     assert len(doc["suppressed"]) >= 4
     assert "repro.analysis:" in capsys.readouterr().out

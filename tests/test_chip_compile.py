@@ -1,0 +1,131 @@
+"""Compile the compat_join kernels and a whole PALLAS slot tick for a
+described TPU v5e, without a chip.
+
+The TPU compiler ships with the installed JAX and compiles for a chip
+that is described, not attached.  It refuses what interpret mode and the
+``repro.analysis`` KC rules cannot see (block shapes, layouts, scalar
+stores to VMEM), so these compiles guard every change to the kernels and
+the slot tick at the widths ``chip_smoke.py`` serves: tables of 16,384
+rows joined against 1,024-edge batches, 64 slots per group.
+
+The topology is described inside a module fixture, never at import: one
+process at a time may load the TPU library, so only the test worker that
+runs this file touches it.
+"""
+
+import os
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
+
+from repro.core.join import JoinBackend
+from repro.core.multi import build_slot_tick, init_slot_state
+from repro.core.plan import compile_plan
+from repro.core.query import QueryGraph
+from repro.core.state import EdgeBatch
+from repro.kernels.compat_join import ops as cj_ops
+
+CA, CB, SLOTS, MAX_NEW = 16384, 1024, 64, 2048
+NVA, NEA, NVB, NEB = 4, 3, 2, 1
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:                      # no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a described chip's compile can be written to the persistent cache
+    # but not read back: keep the cache off around these compiles
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", prev)
+    compilation_cache.reset_cache()
+
+
+def _spec():
+    rel = np.zeros((NVA, NVB), bool)
+    rel[0, 1] = True
+    trel = np.zeros((NEA, NEB), np.int8)
+    trel[-1, 0] = -1
+    return rel, trel
+
+
+def _tables(one_chip, slots=None):
+    def s(*shape, dtype=jnp.int32):
+        lead = () if slots is None else (slots,)
+        return jax.ShapeDtypeStruct(lead + shape, dtype, sharding=one_chip)
+    return (s(CA, NVA), s(CA, NEA), s(CA, dtype=jnp.bool_),
+            s(CB, NVB), s(CB, NEB), s(CB, dtype=jnp.bool_))
+
+
+def _compiled_text(fn, *args):
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+@pytest.mark.parametrize("op", ["compat_mask", "compat_join_pairs"])
+def test_kernel_compiles_unbatched(one_chip, op):
+    rel, trel = _spec()
+    if op == "compat_mask":
+        fn = lambda *t: cj_ops.compat_mask(*t, rel, trel, window=30)
+    else:
+        fn = lambda *t: cj_ops.compat_join_pairs(*t, rel, trel, MAX_NEW,
+                                                 window=30)
+    assert "tpu_custom_call" in _compiled_text(fn, *_tables(one_chip))
+
+
+@pytest.mark.parametrize("op", ["compat_mask", "compat_join_pairs"])
+def test_kernel_compiles_stacked(one_chip, op):
+    """The vmapped form the slot tick runs: per-slot A side, per-slot
+    windows, B bindings/timestamps shared by every slot (the batch)."""
+    rel, trel = _spec()
+    a = _tables(one_chip, SLOTS)[:3]
+    b = _tables(one_chip)[3:5]
+    vb = jax.ShapeDtypeStruct((SLOTS, CB), jnp.bool_, sharding=one_chip)
+    win = jax.ShapeDtypeStruct((SLOTS,), jnp.int32, sharding=one_chip)
+    if op == "compat_mask":
+        one = lambda ba, ea, va, bb, eb, vb, w: cj_ops.compat_mask(
+            ba, ea, va, bb, eb, vb, rel, trel, window=w)
+    else:
+        one = lambda ba, ea, va, bb, eb, vb, w: cj_ops.compat_join_pairs(
+            ba, ea, va, bb, eb, vb, rel, trel, MAX_NEW, window=w)
+    fn = jax.vmap(one, in_axes=(0, 0, 0, None, None, 0, 0))
+    assert "tpu_custom_call" in _compiled_text(fn, *a, *b, vb, win)
+
+
+def _c2_query() -> QueryGraph:
+    """The 5-edge C2 exfiltration chain (vertex labels victim, web,
+    malware, C&C, C&C; one port per edge; a total timing order)."""
+    return QueryGraph(
+        n_vertices=5, vertex_labels=(0, 1, 2, 3, 3),
+        edges=((0, 1), (2, 0), (0, 3), (3, 0), (0, 4)),
+        edge_labels=(1, 2, 3, 4, 5),
+        prec=frozenset({(0, 1), (1, 2), (2, 3), (3, 4)}))
+
+
+def test_pallas_slot_tick_compiles_with_kernels(one_chip):
+    """One whole served slot tick (event-time mode) of the C2 template at
+    deployment capacity compiles for the chip, with the kernels in it."""
+    plan = compile_plan(_c2_query(), 600, level_capacity=CA,
+                        l0_capacity=CA, max_new=MAX_NEW)
+    tick = build_slot_tick(plan, backend=JoinBackend.PALLAS)
+    place = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                           sharding=one_chip)
+    sstate = jax.tree.map(place, jax.eval_shape(
+        lambda: init_slot_state(plan, SLOTS)))
+    batch = EdgeBatch(*(
+        [jax.ShapeDtypeStruct((CB,), jnp.int32, sharding=one_chip)] * 6
+        + [jax.ShapeDtypeStruct((CB,), jnp.bool_, sharding=one_chip)]))
+    wm = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    text = _compiled_text(tick, sstate, batch, wm)
+    assert "tpu_custom_call" in text

@@ -147,8 +147,10 @@ def test_crash_restore_differential(tmp_path, backend):
     svc_b.ckpt.wait()               # flush in-flight async writes
 
     # ---- restore: same tenants, same slots, zero recompiles ------------
+    # the backend is the restoring process's choice, not the manifest's:
+    # a test that pins one passes it again
     svc_r = ContinuousSearchService.restore(str(tmp_path / "b"),
-                                            tick_cache=tc)
+                                            tick_cache=tc, backend=backend)
     assert svc_r.n_compiles == 0
     assert tc.n_builds == builds_a
     assert svc_r.registry.qids() == qids

@@ -355,7 +355,7 @@ def test_crash_restore_differential_with_sharing(tmp_path, backend):
     svc_b.ckpt.wait()
 
     svc_r = ContinuousSearchService.restore(str(tmp_path / "b"),
-                                            tick_cache=tc)
+                                            tick_cache=tc, backend=backend)
     assert tc.n_builds == builds_a        # zero warm recompiles
     assert svc_r.forest is not None
     assert svc_r.forest_stats() == svc_b.forest_stats()
