@@ -603,7 +603,7 @@ class ContinuousSearchService:
         return totals
 
     def _tick_chunk(self, chunk: list, on_match, totals: dict,
-                    watermark=None, min_width: int = 8
+                    watermark=None, *, min_width: int
                     ) -> tuple[float, int, int]:
         """One production tick over ``chunk`` (a DataEdge list): batch
         padded to a power of two of at least ``min_width`` (the
