@@ -84,6 +84,7 @@ def build_sharded_tick(
         match_bindings=P(axes),
         match_ets=P(axes),
         match_valid=P(axes),
+        load=P(),                               # psum'd over shards
     )
 
     in_specs = (specs, batch_specs)

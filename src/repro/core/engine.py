@@ -55,12 +55,52 @@ I32 = jnp.int32
 NO_WATERMARK = int(np.iinfo(np.int32).min)
 
 
+class TickLoad(NamedTuple):
+    """Live-row counters of one tick, as the host reads them back.
+
+    They are sums of validity masks the tick already holds, beside the
+    static row counts they are out of.  Tables are counted after expiry;
+    each ``join_pairs`` call counts the rows it joins on side A and side
+    B against the rows it sweeps there, so ``Σ live_a·live_b / Σ
+    cap_a·cap_b`` is the share of visited pairs that can match.  The
+    tick returns them packed in ONE int32 vector (``TickResult.load``,
+    ``[..., 4 + 4 * n_joins]``), so a group's counters come back in one
+    transfer; ``unpack`` reads it, with any leading slot axes.  Under a
+    capacity-sharded tick (``axis_name``) the vector is psum'd over the
+    shards."""
+
+    level_live: np.ndarray    # live rows, all level tables
+    level_cap: np.ndarray     # their capacity
+    l0_live: np.ndarray       # live rows, all L0 tables
+    l0_cap: np.ndarray        # their capacity
+    join_live_a: np.ndarray   # [..., n_joins]: live rows, side A
+    join_live_b: np.ndarray   # [..., n_joins]: live rows, side B
+    join_cap_a: np.ndarray    # [..., n_joins]: rows swept, side A
+    join_cap_b: np.ndarray    # [..., n_joins]: rows swept, side B
+
+    @classmethod
+    def unpack(cls, packed) -> "TickLoad":
+        p = np.asarray(packed, np.int64)
+        j = (p.shape[-1] - 4) // 4
+        return cls(p[..., 0], p[..., 1], p[..., 2], p[..., 3],
+                   *(p[..., 4 + k * j:4 + (k + 1) * j] for k in range(4)))
+
+    def totals(self) -> tuple[int, int, int, int]:
+        """(live rows, capacity rows, live pairs, capacity pairs), summed
+        over every slot and join; the pair products are exact int64."""
+        return (int(self.level_live.sum() + self.l0_live.sum()),
+                int(self.level_cap.sum() + self.l0_cap.sum()),
+                int((self.join_live_a * self.join_live_b).sum()),
+                int((self.join_cap_a * self.join_cap_b).sum()))
+
+
 class TickResult(NamedTuple):
     n_new_matches: jnp.ndarray     # int32 scalar
     n_overflow: jnp.ndarray       # int32 scalar (this tick)
     match_bindings: jnp.ndarray   # int32 [max_out, nv_total]
     match_ets: jnp.ndarray        # int32 [max_out, ne_total]
     match_valid: jnp.ndarray      # bool  [max_out]
+    load: jnp.ndarray             # int32 [4 + 4*n_joins]: ``TickLoad``
 
 
 class _View(NamedTuple):
@@ -137,6 +177,33 @@ def _compact(view: _View, mask, size: int):
     )
 
 
+def _pack_load(levels, l0, joins: list[tuple]) -> jnp.ndarray:
+    """The tick's packed ``TickLoad`` from its post-expiry tables and the
+    ``(valid_a, valid_b)`` masks of each ``join_pairs`` call.  Masks of
+    one length are summed in one reduction."""
+    tables = [t.valid for sub in levels for t in sub]
+    l0v = [t.valid for t in l0]
+    masks = tables + l0v + [m for pair in joins for m in pair]
+    sums: list = [None] * len(masks)
+    by_len: dict[int, list[int]] = {}
+    for i, m in enumerate(masks):
+        by_len.setdefault(m.shape[-1], []).append(i)
+    for idx in by_len.values():
+        s = jnp.sum(jnp.stack([masks[i] for i in idx]), axis=-1, dtype=I32)
+        for k, i in enumerate(idx):
+            sums[i] = s[k]
+    zero = jnp.zeros((), I32)
+    nt, nl = len(tables), len(l0v)
+    head = [sum(sums[:nt], zero), sum(m.shape[-1] for m in tables),
+            sum(sums[nt:nt + nl], zero), sum(m.shape[-1] for m in l0v)]
+    pairs = sums[nt + nl:]
+    return jnp.stack(
+        [jnp.asarray(x, I32) for x in head]
+        + pairs[0::2] + pairs[1::2]
+        + [jnp.asarray(a.shape[-1], I32) for a, _ in joins]
+        + [jnp.asarray(b.shape[-1], I32) for _, b in joins])
+
+
 def edge_match_mask(batch: EdgeBatch, esl, edl, eel) -> jnp.ndarray:
     """Per-query-edge label match mask ``[n_qedges, B]``.
 
@@ -146,14 +213,16 @@ def edge_match_mask(batch: EdgeBatch, esl, edl, eel) -> jnp.ndarray:
     runtime arrays (the multi-query fused / slot ticks), which is what
     lets a service register a same-shaped query without recompiling.
     """
-    no_selfloop = batch.src != batch.dst
-    return (
-        batch.valid[None, :]
-        & no_selfloop[None, :]
-        & (batch.src_label[None, :] == esl[:, None])
-        & (batch.dst_label[None, :] == edl[:, None])
-        & ((eel[:, None] < 0) | (batch.edge_label[None, :] == eel[:, None]))
-    )
+    with jax.named_scope("engine.label_scan"):
+        no_selfloop = batch.src != batch.dst
+        return (
+            batch.valid[None, :]
+            & no_selfloop[None, :]
+            & (batch.src_label[None, :] == esl[:, None])
+            & (batch.dst_label[None, :] == edl[:, None])
+            & ((eel[:, None] < 0)
+               | (batch.edge_label[None, :] == eel[:, None]))
+        )
 
 
 def build_tick_body(
@@ -258,6 +327,11 @@ def build_tick_body(
 
     def body(state: EngineState, batch: EdgeBatch, ematch, window,
              prefix_view=None, watermark=None):
+        # Every phase runs under a ``jax.named_scope`` (``engine.*``): the
+        # name lands in each op's HLO metadata, so a device profile can
+        # split the tick's time by phase.  Metadata only — no op, fusion
+        # or result changes.
+        #
         # -- 0. advance time; clear last tick's fresh marks ------------ #
         # NOTE: expiry is deferred to the END of the tick.  Mid-tick, the
         # window-span predicate inside every join plays the role of the
@@ -279,49 +353,60 @@ def build_tick_body(
         # sequential replay would.  INT32_MIN means "watermark unknown"
         # and degrades to the frozen/processing clock through the same
         # max/min composition — no branch on the traced value.
-        rejected = jnp.zeros((), I32)
-        if watermark is not None:
-            late = batch.valid & (batch.ts <= state.t_now - window)
-            rejected = jnp.sum(late, dtype=I32)
-            keep = batch.valid & ~late
-            batch = batch._replace(valid=keep)
-            ematch = ematch & keep[None, :]
-        bt = jnp.where(batch.valid, batch.ts, jnp.iinfo(jnp.int32).min)
-        if watermark is None:
-            t_now = jnp.maximum(state.t_now, jnp.max(bt))
-        else:
-            t_now = jnp.maximum(
-                state.t_now, jnp.minimum(watermark, jnp.max(bt)))
-        levels = tuple(
-            tuple(t._replace(fresh=jnp.zeros_like(t.fresh)) for t in sub)
-            for sub in state.levels
-        )
-        l0 = tuple(t._replace(fresh=jnp.zeros_like(t.fresh)) for t in state.l0)
+        with jax.named_scope("engine.label_scan"):
+            rejected = jnp.zeros((), I32)
+            if watermark is not None:
+                late = batch.valid & (batch.ts <= state.t_now - window)
+                rejected = jnp.sum(late, dtype=I32)
+                keep = batch.valid & ~late
+                batch = batch._replace(valid=keep)
+                ematch = ematch & keep[None, :]
+            bt = jnp.where(batch.valid, batch.ts, jnp.iinfo(jnp.int32).min)
+            if watermark is None:
+                t_now = jnp.maximum(state.t_now, jnp.max(bt))
+            else:
+                t_now = jnp.maximum(
+                    state.t_now, jnp.minimum(watermark, jnp.max(bt)))
+            levels = tuple(
+                tuple(t._replace(fresh=jnp.zeros_like(t.fresh)) for t in sub)
+                for sub in state.levels
+            )
+            l0 = tuple(t._replace(fresh=jnp.zeros_like(t.fresh)) for t in state.l0)
 
-        n_overflow = jnp.zeros((), I32)
-        # drops computed on REPLICATED inputs (prefix-view joins under
-        # sharding): every shard counts the same drop, so this bucket is
-        # psum'd then divided by n_shards at the end of the tick
-        n_overflow_repl = jnp.zeros((), I32)
+            n_overflow = jnp.zeros((), I32)
+            # drops computed on REPLICATED inputs (prefix-view joins under
+            # sharding): every shard counts the same drop, so this bucket is
+            # psum'd then divided by n_shards at the end of the tick
+            n_overflow_repl = jnp.zeros((), I32)
 
-        def _own_rows(n):
-            """Round-robin shard ownership mask over a row/pair index."""
-            my_shard = jax.lax.axis_index(axis_name)
-            return (jnp.arange(n) % n_shards) == my_shard
+            def _own_rows(n):
+                """Round-robin shard ownership mask over a row/pair index."""
+                my_shard = jax.lax.axis_index(axis_name)
+                return (jnp.arange(n) % n_shards) == my_shard
 
-        # -- 1. per-query-edge label match mask [n_qedges, B] ---------- #
-        edge_used = jnp.any(ematch, axis=0)
-        n_discard = jnp.sum(batch.valid & ~edge_used, dtype=I32)
+            # -- 1. per-query-edge label match mask [n_qedges, B] ---------- #
+            edge_used = jnp.any(ematch, axis=0)
+            n_discard = jnp.sum(batch.valid & ~edge_used, dtype=I32)
 
-        bbind = jnp.stack([batch.src, batch.dst], axis=1)  # [B, 2]
-        bets = batch.ts[:, None]
+            bbind = jnp.stack([batch.src, batch.dst], axis=1)  # [B, 2]
+            bets = batch.ts[:, None]
 
-        # round-robin ownership of level-1 appends across shards
-        if axis_name is not None:
-            my = jax.lax.axis_index(axis_name)
-            own1 = (jnp.arange(batch.src.shape[0]) % n_shards) == my
-        else:
-            own1 = jnp.ones(batch.src.shape, jnp.bool_)
+            # round-robin ownership of level-1 appends across shards
+            if axis_name is not None:
+                my = jax.lax.axis_index(axis_name)
+                own1 = (jnp.arange(batch.src.shape[0]) % n_shards) == my
+            else:
+                own1 = jnp.ones(batch.src.shape, jnp.bool_)
+
+        # both sides' validity of every join_pairs call (TickLoad)
+        joins: list[tuple] = []
+
+        def _join(bind_a, ets_a, valid_a, bind_b, ets_b, valid_b, rel,
+                  trel, max_new):
+            joins.append((valid_a, valid_b))
+            return J.join_pairs(bind_a, ets_a, valid_a, bind_b, ets_b,
+                                valid_b, rel, trel, max_new, window,
+                                backend)
 
         # -- 2. subquery phase: level-ordered batched inserts ---------- #
         recons: list[list[_View]] = []
@@ -343,51 +428,57 @@ def build_tick_body(
                 ti = li - start          # index into the (suffix) tables
                 em = ematch[lv.qedge]
                 if li == 0:
-                    t, nd = _append_level(
-                        sub[0], jnp.full_like(batch.src, -1),
-                        batch.src, batch.dst, batch.ts, em & own1)
+                    with jax.named_scope("engine.level_append"):
+                        t, nd = _append_level(
+                            sub[0], jnp.full_like(batch.src, -1),
+                            batch.src, batch.dst, batch.ts, em & own1)
                     sub[0] = t
                     n_overflow += nd
                 else:
                     prev = sub_recons[-1]
-                    a_idx, b_idx, pv, nd1 = J.join_pairs(
-                        prev.bind, prev.ets, prev.valid,
-                        bbind, bets, em,
-                        level_rel[(si, li)], _trel_chain(prev.ets.shape[1]),
-                        lv.max_new, window, backend)
-                    if axis_name is not None and li == start and start:
-                        # left side is the replicated prefix view: every
-                        # shard computed the same pairs — partition them
-                        # deterministically so each lands exactly once
-                        pv = pv & _own_rows(pv.shape[0])
-                        n_overflow_repl += nd1
-                    else:
-                        n_overflow += nd1
-                    t, nd2 = _append_level(
-                        sub[ti], a_idx,
-                        jnp.take(batch.src, b_idx, mode="clip"),
-                        jnp.take(batch.dst, b_idx, mode="clip"),
-                        jnp.take(batch.ts, b_idx, mode="clip"),
-                        pv)
+                    with jax.named_scope("engine.level_join"):
+                        a_idx, b_idx, pv, nd1 = _join(
+                            prev.bind, prev.ets, prev.valid,
+                            bbind, bets, em,
+                            level_rel[(si, li)],
+                            _trel_chain(prev.ets.shape[1]), lv.max_new)
+                    with jax.named_scope("engine.level_append"):
+                        if axis_name is not None and li == start and start:
+                            # left side is the replicated prefix view:
+                            # every shard computed the same pairs —
+                            # partition them deterministically so each
+                            # lands exactly once
+                            pv = pv & _own_rows(pv.shape[0])
+                            n_overflow_repl += nd1
+                        else:
+                            n_overflow += nd1
+                        t, nd2 = _append_level(
+                            sub[ti], a_idx,
+                            jnp.take(batch.src, b_idx, mode="clip"),
+                            jnp.take(batch.dst, b_idx, mode="clip"),
+                            jnp.take(batch.ts, b_idx, mode="clip"),
+                            pv)
                     sub[ti] = t
                     n_overflow += nd2
                 # reconstruct this level's denormalized view (post-append)
-                t = sub[ti]
-                if li == 0:
-                    bind = jnp.stack([t.src, t.dst], axis=1)
-                    ets = t.ts[:, None]
-                else:
-                    p = jnp.maximum(t.parent, 0)
-                    prevv = sub_recons[-1]
-                    cols = [jnp.take(prevv.bind, p, axis=0)]
-                    own = []
-                    if lv.src_slot < 0:
-                        own.append(t.src[:, None])
-                    if lv.dst_slot < 0:
-                        own.append(t.dst[:, None])
-                    bind = jnp.concatenate(cols + own, axis=1)
-                    ets = jnp.concatenate(
-                        [jnp.take(prevv.ets, p, axis=0), t.ts[:, None]], axis=1)
+                with jax.named_scope("engine.level_recon"):
+                    t = sub[ti]
+                    if li == 0:
+                        bind = jnp.stack([t.src, t.dst], axis=1)
+                        ets = t.ts[:, None]
+                    else:
+                        p = jnp.maximum(t.parent, 0)
+                        prevv = sub_recons[-1]
+                        cols = [jnp.take(prevv.bind, p, axis=0)]
+                        own = []
+                        if lv.src_slot < 0:
+                            own.append(t.src[:, None])
+                        if lv.dst_slot < 0:
+                            own.append(t.dst[:, None])
+                        bind = jnp.concatenate(cols + own, axis=1)
+                        ets = jnp.concatenate(
+                            [jnp.take(prevv.ets, p, axis=0), t.ts[:, None]],
+                            axis=1)
                 sub_recons.append(_View(bind, ets, t.valid, t.fresh))
             recons.append(sub_recons)
             new_levels.append(tuple(sub))
@@ -408,57 +499,63 @@ def build_tick_body(
             d = js.max_new
 
             # J1: ΔA ⋈ B (old ∪ Δ)
-            da, _, nd0 = _compact(a_view, a_view.fresh & a_view.valid, d)
-            if a_repl:
-                n_overflow_repl += nd0
-            else:
-                n_overflow += nd0
-            if axis_name is not None and not a_repl:
-                da = _View(*(
-                    jax.lax.all_gather(x, axis_name, axis=0, tiled=True)
-                    for x in da))
-            a1, b1, pv1, nd1 = J.join_pairs(
-                da.bind, da.ets, da.valid,
-                b_view.bind, b_view.ets, b_view.valid,
-                js.rel, js.trel, d, window, backend)
-            nb = jnp.take(b_view.bind, b1, axis=0, mode="clip")
-            out_bind1 = jnp.concatenate(
-                [jnp.take(da.bind, a1, axis=0, mode="clip")]
-                + ([nb[:, list(js.b_new_vertex_slots)]]
-                   if js.b_new_vertex_slots else []),
-                axis=1)
-            out_ets1 = jnp.concatenate(
-                [jnp.take(da.ets, a1, axis=0, mode="clip"),
-                 jnp.take(b_view.ets, b1, axis=0, mode="clip")], axis=1)
-            tbl, nd2 = _append_l0(tbl, out_bind1, out_ets1, pv1)
+            with jax.named_scope("engine.l0_compact"):
+                da, _, nd0 = _compact(a_view, a_view.fresh & a_view.valid, d)
+                if a_repl:
+                    n_overflow_repl += nd0
+                else:
+                    n_overflow += nd0
+                if axis_name is not None and not a_repl:
+                    da = _View(*(
+                        jax.lax.all_gather(x, axis_name, axis=0, tiled=True)
+                        for x in da))
+            with jax.named_scope("engine.l0_join"):
+                a1, b1, pv1, nd1 = _join(
+                    da.bind, da.ets, da.valid,
+                    b_view.bind, b_view.ets, b_view.valid,
+                    js.rel, js.trel, d)
+            with jax.named_scope("engine.l0_append"):
+                nb = jnp.take(b_view.bind, b1, axis=0, mode="clip")
+                out_bind1 = jnp.concatenate(
+                    [jnp.take(da.bind, a1, axis=0, mode="clip")]
+                    + ([nb[:, list(js.b_new_vertex_slots)]]
+                       if js.b_new_vertex_slots else []),
+                    axis=1)
+                out_ets1 = jnp.concatenate(
+                    [jnp.take(da.ets, a1, axis=0, mode="clip"),
+                     jnp.take(b_view.ets, b1, axis=0, mode="clip")], axis=1)
+                tbl, nd2 = _append_l0(tbl, out_bind1, out_ets1, pv1)
 
             # J2: A_old ⋈ ΔB
-            db, _, nd3 = _compact(b_view, b_view.fresh & b_view.valid, d)
-            if axis_name is not None:
-                db = _View(*(
-                    jax.lax.all_gather(x, axis_name, axis=0, tiled=True)
-                    for x in db))
-            a2, b2, pv2, nd4 = J.join_pairs(
-                a_view.bind, a_view.ets, a_view.valid & ~a_view.fresh,
-                db.bind, db.ets, db.valid,
-                js.rel, js.trel, d, window, backend)
-            if axis_name is not None and a_repl:
-                # replicated A × gathered (replicated) ΔB: identical
-                # pairs on every shard — partition before append
-                pv2 = pv2 & _own_rows(pv2.shape[0])
-                n_overflow_repl += nd4
-            else:
-                n_overflow += nd4
-            nb2 = jnp.take(db.bind, b2, axis=0, mode="clip")
-            out_bind2 = jnp.concatenate(
-                [jnp.take(a_view.bind, a2, axis=0, mode="clip")]
-                + ([nb2[:, list(js.b_new_vertex_slots)]]
-                   if js.b_new_vertex_slots else []),
-                axis=1)
-            out_ets2 = jnp.concatenate(
-                [jnp.take(a_view.ets, a2, axis=0, mode="clip"),
-                 jnp.take(db.ets, b2, axis=0, mode="clip")], axis=1)
-            tbl, nd5 = _append_l0(tbl, out_bind2, out_ets2, pv2)
+            with jax.named_scope("engine.l0_compact"):
+                db, _, nd3 = _compact(b_view, b_view.fresh & b_view.valid, d)
+                if axis_name is not None:
+                    db = _View(*(
+                        jax.lax.all_gather(x, axis_name, axis=0, tiled=True)
+                        for x in db))
+            with jax.named_scope("engine.l0_join"):
+                a2, b2, pv2, nd4 = _join(
+                    a_view.bind, a_view.ets, a_view.valid & ~a_view.fresh,
+                    db.bind, db.ets, db.valid,
+                    js.rel, js.trel, d)
+            with jax.named_scope("engine.l0_append"):
+                if axis_name is not None and a_repl:
+                    # replicated A × gathered (replicated) ΔB: identical
+                    # pairs on every shard — partition before append
+                    pv2 = pv2 & _own_rows(pv2.shape[0])
+                    n_overflow_repl += nd4
+                else:
+                    n_overflow += nd4
+                nb2 = jnp.take(db.bind, b2, axis=0, mode="clip")
+                out_bind2 = jnp.concatenate(
+                    [jnp.take(a_view.bind, a2, axis=0, mode="clip")]
+                    + ([nb2[:, list(js.b_new_vertex_slots)]]
+                       if js.b_new_vertex_slots else []),
+                    axis=1)
+                out_ets2 = jnp.concatenate(
+                    [jnp.take(a_view.ets, a2, axis=0, mode="clip"),
+                     jnp.take(db.ets, b2, axis=0, mode="clip")], axis=1)
+                tbl, nd5 = _append_l0(tbl, out_bind2, out_ets2, pv2)
 
             n_overflow += nd1 + nd2 + nd3 + nd5
             new_l0.append(tbl)
@@ -469,33 +566,38 @@ def build_tick_body(
         # -- 4. emit (before end-of-tick expiry: a match created mid-tick
         #       is reported even if it expires within the same tick,
         #       matching sequential replay) --------------------------- #
-        final = a_view
-        new_mask = final.fresh & final.valid
-        if axis_name is not None and a_repl:
-            # fully-prefixed chain query: the final view is replicated —
-            # partition emission so each match is reported exactly once
-            new_mask = new_mask & _own_rows(new_mask.shape[0])
-        n_new = jnp.sum(new_mask, dtype=I32)
-        if axis_name is not None:
-            n_new = jax.lax.psum(n_new, axis_name)
-        if extract_matches:
-            out, _, nd = _compact(final, new_mask, max_out)
-            mb, me, mv = out.bind, out.ets, out.valid
-            n_overflow += nd
-        else:
-            mb = jnp.zeros((max_out, nv_final), I32)
-            me = jnp.zeros((max_out, ne_final), I32)
-            mv = jnp.zeros((max_out,), jnp.bool_)
+        with jax.named_scope("engine.emit"):
+            final = a_view
+            new_mask = final.fresh & final.valid
+            if axis_name is not None and a_repl:
+                # fully-prefixed chain query: the final view is
+                # replicated — partition emission so each match is
+                # reported exactly once
+                new_mask = new_mask & _own_rows(new_mask.shape[0])
+            n_new = jnp.sum(new_mask, dtype=I32)
+            if axis_name is not None:
+                n_new = jax.lax.psum(n_new, axis_name)
+            if extract_matches:
+                out, _, nd = _compact(final, new_mask, max_out)
+                mb, me, mv = out.bind, out.ets, out.valid
+                n_overflow += nd
+            else:
+                mb = jnp.zeros((max_out, nv_final), I32)
+                me = jnp.zeros((max_out, ne_final), I32)
+                mv = jnp.zeros((max_out,), jnp.bool_)
 
         # -- 5. end-of-tick expiry ------------------------------------- #
-        levels, l0 = _expire(
-            levels, l0, t_now - window,
-            prefix_view.valid_after if prefix_depth else None)
+        with jax.named_scope("engine.expire"):
+            levels, l0 = _expire(
+                levels, l0, t_now - window,
+                prefix_view.valid_after if prefix_depth else None)
+            load = _pack_load(levels, l0, joins)
 
         if axis_name is not None:
             n_overflow = jax.lax.psum(n_overflow, axis_name) \
                 + jax.lax.psum(n_overflow_repl, axis_name) // n_shards
             n_discard = jax.lax.psum(n_discard, axis_name) // n_shards
+            load = jax.lax.psum(load, axis_name)
         else:
             n_overflow = n_overflow + n_overflow_repl
 
@@ -508,7 +610,7 @@ def build_tick_body(
             n_edges_rejected=state.stats.n_edges_rejected + rejected,
         )
         new_state = EngineState(levels=levels, l0=l0, t_now=t_now, stats=stats)
-        return new_state, TickResult(n_new, n_overflow, mb, me, mv)
+        return new_state, TickResult(n_new, n_overflow, mb, me, mv, load)
 
     return body
 

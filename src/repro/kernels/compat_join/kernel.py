@@ -219,6 +219,7 @@ def compat_mask_kernel(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n_slots, ca, cb), jnp.int8),
         interpret=interpret,
+        name="compat_mask",
     )(window, a, b)
 
 
@@ -322,4 +323,5 @@ def compat_join_pairs_kernel(
             jax.ShapeDtypeStruct((n_slots, 1, _LANE), jnp.int32),
         ],
         interpret=interpret,
+        name="compat_join_pairs",
     )(window, a, b)

@@ -3,8 +3,9 @@
 * :class:`MetricsRegistry` / :class:`Counter` / :class:`Gauge` /
   :class:`Histogram`: the namespaced instrument registry every stat
   surface (ingest, coalescer, tick, share, ckpt, mesh) reports into.
-* :class:`Tracer`: host-side JSONL span timers with per-tick
-  correlation ids — strictly outside traced/jitted code.
+* :class:`Tracer`: host-side nested spans (ids, parents, monotonic ns,
+  per-tick correlation ids), each a ``repro.*`` profiler annotation
+  while open, written as JSONL on flush — strictly outside traced code.
 * :func:`to_prometheus`: text exposition snapshot.
 * :func:`summarize_trace`: the ``python -m repro.obs summarize`` CLI.
 
@@ -21,7 +22,7 @@ from .metrics import (
     percentile,
 )
 from .summarize import format_summary, summarize_trace
-from .trace import Span, Tracer, memory_tracer
+from .trace import NULL_SPAN, Span, Tracer, maybe_span, memory_tracer
 
 __all__ = [
     "Counter",
@@ -32,6 +33,8 @@ __all__ = [
     "DEFAULT_LATENCY_BUCKETS_MS",
     "Tracer",
     "Span",
+    "NULL_SPAN",
+    "maybe_span",
     "memory_tracer",
     "to_prometheus",
     "summarize_trace",

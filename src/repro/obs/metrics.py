@@ -4,9 +4,9 @@ One process-local :class:`MetricsRegistry` unifies every stat surface in
 the repo (``ServeInfo``, ``EngineStats``, ``SessionStatus``,
 ``forest_stats()``, ``MeshTickStats``) under a namespaced scheme::
 
-    ingest.*     frontier counters, watermark lag
+    ingest.*     frontier counters, watermark lag, hold times
     coalescer.*  AIMD batch decisions
-    tick.*       slot-tick latency, matches, overflow
+    tick.*       slot-tick latency, matches, overflow, live table rows
     share.*      prefix-forest shape
     ckpt.*       checkpoint publish latency, async stall
     mesh.*       per-replica load / pressure

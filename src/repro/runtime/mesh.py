@@ -18,8 +18,9 @@ tenants high and shards the SLOT axis over a 1-D device mesh
   is the vmap over the local block, nothing crosses replicas;
 * the tick body itself runs with ``axis_name=None`` — tenants are
   independent, so the hot loop has ZERO collectives; the only
-  cross-replica traffic is three scalar reductions per tick
-  (``MeshTickStats``: matched/overflow psums + a pmax watermark clock);
+  cross-replica traffic is five scalar reductions per tick
+  (``MeshTickStats``: matched/overflow and live/allocated table-row
+  psums + a pmax watermark clock);
 * a ``PlacementPolicy`` decides which replica each newly registered
   tenant lands on (round-robin, or load-balanced by tenant count and
   ``overflow_pressure``); the slot search inside the chosen replica's
@@ -90,6 +91,8 @@ class MeshTickStats(NamedTuple):
     n_matches: jnp.ndarray    # psum of new matches over all replicas
     n_overflow: jnp.ndarray   # psum of dropped appends over all replicas
     t_clock: jnp.ndarray      # pmax of every replica's engine clock
+    live_rows: jnp.ndarray    # psum of live table rows (``TickLoad``)
+    capacity_rows: jnp.ndarray  # psum of allocated table rows
 
 
 # --------------------------------------------------------------------- #
@@ -136,6 +139,10 @@ def build_mesh_slot_tick(
             n_overflow=jax.lax.psum(
                 jnp.sum(res.n_overflow).astype(I32), axis),
             t_clock=jax.lax.pmax(jnp.max(sstate.engines.t_now), axis),
+            live_rows=jax.lax.psum(
+                jnp.sum(res.load[:, 0] + res.load[:, 2]), axis),
+            capacity_rows=jax.lax.psum(
+                jnp.sum(res.load[:, 1] + res.load[:, 3]), axis),
         )
         return sstate, res, stats
 
@@ -162,7 +169,7 @@ def build_mesh_slot_tick(
                     return _finish(*inner(sstate, batch, view))
                 in_specs = (state_spec, repl, repl)
         out_specs = (state_spec, state_spec,
-                     MeshTickStats(repl, repl, repl))
+                     MeshTickStats(*(repl for _ in MeshTickStats._fields)))
         return jax.jit(
             jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
                           out_specs=out_specs, check_vma=False),
@@ -399,9 +406,7 @@ class ShardedSearchService(ContinuousSearchService):
 
     def last_mesh_stats(self) -> dict[int, dict]:
         """Host values of every group's last-tick ``MeshTickStats``."""
-        return {gid: {"n_matches": int(s.n_matches),
-                      "n_overflow": int(s.n_overflow),
-                      "t_clock": int(s.t_clock)}
+        return {gid: {k: int(v) for k, v in s._asdict().items()}
                 for gid, s in self.mesh_stats.items()}
 
     def _register_obs_gauges(self) -> None:

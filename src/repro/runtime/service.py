@@ -117,7 +117,7 @@ from repro.core.multi import (
     read_slot,
     write_slot,
 )
-from repro.core.engine import TickResult, current_matches
+from repro.core.engine import TickLoad, TickResult, current_matches
 from repro.core.plan import ExecutionPlan
 from repro.core.query import QueryGraph
 from repro.core.registry import (
@@ -132,7 +132,7 @@ from repro.core.share import (
 )
 from repro.core.engine import NO_WATERMARK
 from repro.core.state import EdgeBatch, EngineState, init_state, make_batch
-from repro.obs import MetricsRegistry, Tracer
+from repro.obs import MetricsRegistry, Tracer, maybe_span
 from repro.runtime.straggler import TickCoalescer, quantize_pow2
 from repro.stream.generator import to_batches
 
@@ -167,6 +167,17 @@ class ServeInfo(NamedTuple):
     n_dropped_forced_gap: int = 0    # capacity-pressure drops this tick
     watermark_lag: int = 0           # freshest data ts − watermark
     window_staleness: int = 0        # emit floor − watermark (forced gap)
+    # live-row counters of this tick (``repro.core.engine.TickLoad``),
+    # summed over every slot the tick ran: live and allocated rows of
+    # the level and L0 tables after expiry, and over every join call
+    # Σ live_a·live_b against Σ cap_a·cap_b (the pairs a kernel visits)
+    live_rows: int = 0
+    capacity_rows: int = 0
+    live_pairs: int = 0
+    capacity_pairs: int = 0
+    # ``serve_frontier`` only: each released record's hold in the
+    # frontier's reorder buffer, ms (``IngestFrontier.take_ready``)
+    hold_ms: tuple = ()
 
 
 @dataclass(eq=False)       # identity semantics: fields hold device arrays
@@ -578,103 +589,85 @@ class ContinuousSearchService:
         totals: dict[int, int] = {}
         i, n = 0, len(edges)
         while i < n:
-            chunk = edges[i:i + coalescer.batch]
-            queue_depth = n - (i + len(chunk))
-            lat_ms, tick_overflow, n_shared = self._tick_chunk(
-                chunk, on_match, totals, min_width=coalescer.min_batch)
-            # overflow joins latency and queue depth as a throttle input:
-            # dropped appends mean the tick was too big for the tables
-            coalescer.record(lat_ms, queue_depth, tick_overflow)
-            if self.obs is not None:
-                self._observe_coalescer(coalescer)
-            i += len(chunk)
-            if self.ckpt and ckpt_every and self.n_ticks % ckpt_every == 0:
-                self.checkpoint()
-            if on_tick is not None:
-                on_tick(ServeInfo(
-                    tick=self.n_ticks,
-                    n_edges_ingested=self.n_edges_ingested,
-                    chunk=len(chunk),
-                    latency_ms=lat_ms,
-                    n_overflow=tick_overflow,
-                    n_shared_prefix_ticks=n_shared,
-                ))
+            tr = self.tracer
+            if tr is not None:
+                tr.next_tick()
+            try:
+                with maybe_span(tr, "serve.round"):
+                    chunk = edges[i:i + coalescer.batch]
+                    queue_depth = n - (i + len(chunk))
+                    info = self._tick_chunk(
+                        chunk, on_match, totals,
+                        min_width=coalescer.min_batch)
+                    # overflow joins latency and queue depth as a
+                    # throttle input: dropped appends mean the tick was
+                    # too big for the tables
+                    coalescer.record(info.latency_ms, queue_depth,
+                                     info.n_overflow)
+                    if self.obs is not None:
+                        self._observe_coalescer(coalescer)
+                    i += len(chunk)
+                    if self.ckpt and ckpt_every and \
+                            self.n_ticks % ckpt_every == 0:
+                        self.checkpoint()
+                    if on_tick is not None:
+                        with maybe_span(tr, "serve.on_tick"):
+                            on_tick(info)
+            finally:
+                if tr is not None:
+                    tr.flush()
         self._final_checkpoint(ckpt_every, final_checkpoint)
         return totals
 
     def _tick_chunk(self, chunk: list, on_match, totals: dict,
-                    watermark=None, *, min_width: int
-                    ) -> tuple[float, int, int]:
+                    watermark=None, *, min_width: int) -> ServeInfo:
         """One production tick over ``chunk`` (a DataEdge list): batch
         padded to a power of two of at least ``min_width`` (the
         coalescer's floor, so a fixed batch size is one jit shape however
         ragged the released chunks are), async group dispatch, ONE
-        barrier, match delivery (one host read per group).
-        Updates ``totals``/counters in place; returns (barrier latency
-        ms, tick overflow, shared-prefix node count).  Shared by
-        ``serve_stream`` (arrival-order chunks, ``watermark=None``) and
-        ``serve_frontier`` (watermark-order chunks with the frontier's
-        traced event-time watermark)."""
+        barrier, match delivery (one host read per group, which also
+        brings back the group's live-row counters).  Updates
+        ``totals``/counters in place; returns the tick's ``ServeInfo``
+        (frontier fields at their defaults).  Shared by ``serve_stream``
+        (arrival-order chunks, ``watermark=None``) and ``serve_frontier``
+        (watermark-order chunks with the frontier's traced event-time
+        watermark).
+
+        With a tracer the tick is the span ``tick``, holding
+        ``tick.forest`` (only when a forest exists), ``tick.dispatch``,
+        ``tick.barrier`` and ``tick.deliver``; the last holds, per group,
+        ``tick.readback`` (the device reads) and ``tick.callbacks`` (the
+        ``on_match`` calls, where the api layer builds its records)."""
         tr = self.tracer
-        if tr is not None:
-            tr.next_tick()
-        active = [g for g in self._iter_groups() if not g.idle]
-        batch = make_batch(
-            **to_batches(chunk, quantize_pow2(len(chunk), lo=min_width))[0])
-        t0 = time.perf_counter()
-        views, forest_nds = self._advance_forest(batch, watermark)
-        if tr is None:
-            results = [(g, self._advance_group(g, batch, views,
-                                               forest_nds, watermark))
-                       for g in active]
-        else:
-            # per-stage wall clocks via bare perf_counter reads + post-
-            # hoc record(): the tracer-off branch above allocates no
-            # span objects and reads no extra clocks
-            tr.record("tick.forest",
-                      (time.perf_counter() - t0) * 1e3, n_nodes=len(views))
-            results = []
-            for g in active:
-                ts = time.perf_counter()
-                results.append((g, self._advance_group(
-                    g, batch, views, forest_nds, watermark)))
-                tr.record("tick.slot_dispatch",
-                          (time.perf_counter() - ts) * 1e3, gid=g.gid)
-            tb = time.perf_counter()
-        jax.block_until_ready(                              # the barrier
-            [g.sstate for g in active]
-            + ([] if self.forest is None else self.forest.states()))
-        t_end = time.perf_counter()
-        lat_ms = (t_end - t0) * 1e3
-        if tr is not None:
-            tr.record("tick.barrier", (t_end - tb) * 1e3)
-            self._trace_tick_extras(tr)
-        tick_overflow = 0
-        n_matches = 0
-        for g, res in results:
-            # the group's [S] counters come back in one transfer; match
-            # rows only when a slot of this group has new matches
-            n_new_s, n_ov_s = jax.device_get(
-                (res.n_new_matches, res.n_overflow))
-            rows = None
-            for k, qid in enumerate(g.qids):
-                if qid is None:
-                    continue
-                n_new = int(n_new_s[k])
-                tick_overflow += int(n_ov_s[k])
-                n_matches += n_new
-                totals[qid] = totals.get(qid, 0) + n_new
-                if n_new and on_match is not None:
-                    if rows is None:
-                        rows = jax.device_get((res.match_bindings,
-                                               res.match_ets,
-                                               res.match_valid))
-                    mb, me, mv = (x[k] for x in rows)
-                    on_match(qid, mb[mv], me[mv])
-        if tr is not None:
-            tr.record("tick.deliver",
-                      (time.perf_counter() - t_end) * 1e3,
-                      n_matches=n_matches)
+        with maybe_span(tr, "tick") as tick_span:
+            active = [g for g in self._iter_groups() if not g.idle]
+            batch = make_batch(**to_batches(
+                chunk, quantize_pow2(len(chunk), lo=min_width))[0])
+            t0 = time.perf_counter()
+            views, forest_nds = {}, {}
+            if self.forest is not None and len(self.forest):
+                with maybe_span(tr, "tick.forest"):
+                    views, forest_nds = self._advance_forest(batch,
+                                                             watermark)
+            with maybe_span(tr, "tick.dispatch"):
+                results = [(g, self._advance_group(g, batch, views,
+                                                   forest_nds, watermark))
+                           for g in active]
+            with maybe_span(tr, "tick.barrier"):
+                jax.block_until_ready(
+                    [g.sstate for g in active]
+                    + ([] if self.forest is None else self.forest.states()))
+            lat_ms = (time.perf_counter() - t0) * 1e3
+            if tr is not None:
+                self._trace_tick_extras(tr)
+            with maybe_span(tr, "tick.deliver") as deliver:
+                n_matches, tick_overflow, load = self._deliver(
+                    results, on_match, totals)
+            if tr is not None:
+                deliver.set(n_matches=n_matches)
+                tick_span.set(chunk=len(chunk), live_rows=load[0],
+                              capacity_rows=load[1], live_pairs=load[2],
+                              capacity_pairs=load[3])
         self.n_ticks += 1
         self.n_edges_ingested += len(chunk)
         obs = self.obs
@@ -684,9 +677,49 @@ class ContinuousSearchService:
             obs.counter("tick.n_edges").inc(len(chunk))
             obs.counter("tick.n_matches").inc(n_matches)
             obs.counter("tick.n_overflow").inc(tick_overflow)
+            obs.gauge("tick.live_rows").set(load[0])
+            obs.gauge("tick.capacity_rows").set(load[1])
             if views:
                 obs.counter("share.n_prefix_ticks").inc(len(views))
-        return lat_ms, tick_overflow, len(views)
+        return ServeInfo(
+            tick=self.n_ticks, n_edges_ingested=self.n_edges_ingested,
+            chunk=len(chunk), latency_ms=lat_ms, n_overflow=tick_overflow,
+            n_shared_prefix_ticks=len(views), live_rows=load[0],
+            capacity_rows=load[1], live_pairs=load[2],
+            capacity_pairs=load[3])
+
+    def _deliver(self, results, on_match, totals: dict
+                 ) -> tuple[int, int, tuple[int, int, int, int]]:
+        """Read back each group's tick result and deliver its matches.
+        Returns (new matches, overflow, ``TickLoad.totals()`` summed over
+        the groups)."""
+        tr = self.tracer
+        n_matches = tick_overflow = 0
+        load = (0, 0, 0, 0)
+        for g, res in results:
+            armed = [(k, qid) for k, qid in enumerate(g.qids)
+                     if qid is not None]
+            with maybe_span(tr, "tick.readback"):
+                # the group's [S] counters come back in one transfer;
+                # match rows only when an armed slot has new matches
+                n_new_s, n_ov_s, g_load = jax.device_get(
+                    (res.n_new_matches, res.n_overflow, res.load))
+                rows = None
+                if on_match is not None and any(n_new_s[k] for k, _ in armed):
+                    rows = jax.device_get((res.match_bindings,
+                                           res.match_ets, res.match_valid))
+            with maybe_span(tr, "tick.callbacks"):
+                for k, qid in armed:
+                    n_new = int(n_new_s[k])
+                    tick_overflow += int(n_ov_s[k])
+                    n_matches += n_new
+                    totals[qid] = totals.get(qid, 0) + n_new
+                    if n_new and rows is not None:
+                        mb, me, mv = (x[k] for x in rows)
+                        on_match(qid, mb[mv], me[mv])
+            load = tuple(a + b for a, b in
+                         zip(load, TickLoad.unpack(g_load).totals()))
+        return n_matches, tick_overflow, load
 
     def _trace_tick_extras(self, tr: Tracer) -> None:
         """Tracer-on hook after the tick barrier — the mesh service
@@ -781,65 +814,75 @@ class ContinuousSearchService:
         self._frontier = frontier
         prev = frontier.stats()
         idle = 0
+        new_tick = True        # the next traced round opens a tick id
         while not frontier.exhausted:
             tr = self.tracer
-            t_pump = time.perf_counter() if tr is not None else 0.0
-            frontier.pump(pump_size)
-            t_rel = time.perf_counter() if tr is not None else 0.0
-            chunk = frontier.take_ready(limit=coalescer.batch)
-            t_done = time.perf_counter() if tr is not None else 0.0
-            if not chunk:
-                idle += 1
-                coalescer.record_idle()
-                if self.obs is not None:
-                    self._observe_coalescer(coalescer)
-                if max_idle_rounds is not None and idle > max_idle_rounds:
-                    break
-                continue
-            idle = 0
-            # the frontier's event-time watermark drives every engine's
-            # admission/expiry clock this tick.  Traced scalar (one jit
-            # specialization for the whole event-time mode, not one per
-            # value); NO_WATERMARK is the traced "unknown yet" identity.
-            wm = frontier.watermark()
-            wm_in = jnp.asarray(
-                NO_WATERMARK if wm is None else wm, jnp.int32)
-            lat_ms, tick_overflow, n_shared = self._tick_chunk(
-                chunk, on_match, totals, wm_in,
-                min_width=coalescer.min_batch)
-            if tr is not None:
-                # recorded after _tick_chunk so the spans carry this
-                # tick's correlation id (next_tick advances in there)
-                tr.record("ingest.pump", (t_rel - t_pump) * 1e3)
-                tr.record("ingest.release", (t_done - t_rel) * 1e3,
-                          n_released=len(chunk))
-            coalescer.record(lat_ms, frontier.buffered, tick_overflow)
-            if self.obs is not None:
-                self._observe_coalescer(coalescer)
-                frontier.publish_obs(self.obs)
-            if self.ckpt and ckpt_every and \
-                    self.n_ticks % ckpt_every == 0:
-                self.checkpoint()
-            if on_tick is not None:
-                cur = frontier.stats()
-                on_tick(ServeInfo(
-                    tick=self.n_ticks,
-                    n_edges_ingested=self.n_edges_ingested,
-                    chunk=len(chunk),
-                    latency_ms=lat_ms,
-                    n_overflow=tick_overflow,
-                    n_shared_prefix_ticks=n_shared,
-                    watermark=cur.watermark,
-                    n_late_dropped=cur.n_late_dropped
-                    - prev.n_late_dropped,
-                    n_duplicates=cur.n_duplicates - prev.n_duplicates,
-                    n_reconnects=cur.n_reconnects - prev.n_reconnects,
-                    n_dropped_forced_gap=cur.n_dropped_forced_gap
-                    - prev.n_dropped_forced_gap,
-                    watermark_lag=cur.watermark_lag,
-                    window_staleness=cur.window_staleness,
-                ))
-                prev = cur
+            if tr is not None and new_tick:
+                # rounds that wait for data share the id of the tick
+                # they lead to
+                tr.next_tick()
+                new_tick = False
+            try:
+                with maybe_span(tr, "serve.round"):
+                    with maybe_span(tr, "ingest.pump"):
+                        frontier.pump(pump_size)
+                    with maybe_span(tr, "ingest.release") as rel:
+                        chunk = frontier.take_ready(limit=coalescer.batch)
+                    holds = frontier.last_holds_ms
+                    if tr is not None:
+                        rel.set(n_released=len(chunk),
+                                hold_ms=[round(h, 3) for h in holds])
+                    if not chunk:
+                        idle += 1
+                        coalescer.record_idle()
+                        if self.obs is not None:
+                            self._observe_coalescer(coalescer)
+                        if max_idle_rounds is not None and \
+                                idle > max_idle_rounds:
+                            break
+                        continue
+                    idle = 0
+                    # the frontier's event-time watermark drives every
+                    # engine's admission/expiry clock this tick.  Traced
+                    # scalar (one jit specialization for the whole
+                    # event-time mode, not one per value); NO_WATERMARK
+                    # is the traced "unknown yet" identity.
+                    wm = frontier.watermark()
+                    wm_in = jnp.asarray(
+                        NO_WATERMARK if wm is None else wm, jnp.int32)
+                    info = self._tick_chunk(
+                        chunk, on_match, totals, wm_in,
+                        min_width=coalescer.min_batch)
+                    new_tick = True
+                    coalescer.record(info.latency_ms, frontier.buffered,
+                                     info.n_overflow)
+                    if self.obs is not None:
+                        self._observe_coalescer(coalescer)
+                        frontier.publish_obs(self.obs)
+                    if self.ckpt and ckpt_every and \
+                            self.n_ticks % ckpt_every == 0:
+                        self.checkpoint()
+                    if on_tick is not None:
+                        cur = frontier.stats()
+                        info = info._replace(
+                            watermark=cur.watermark,
+                            n_late_dropped=cur.n_late_dropped
+                            - prev.n_late_dropped,
+                            n_duplicates=cur.n_duplicates
+                            - prev.n_duplicates,
+                            n_reconnects=cur.n_reconnects
+                            - prev.n_reconnects,
+                            n_dropped_forced_gap=cur.n_dropped_forced_gap
+                            - prev.n_dropped_forced_gap,
+                            watermark_lag=cur.watermark_lag,
+                            window_staleness=cur.window_staleness,
+                            hold_ms=tuple(holds))
+                        prev = cur
+                        with maybe_span(tr, "serve.on_tick"):
+                            on_tick(info)
+            finally:
+                if tr is not None:
+                    tr.flush()
         self._final_checkpoint(ckpt_every, final_checkpoint)
         return totals
 
@@ -944,36 +987,37 @@ class ContinuousSearchService:
         """
         if self.ckpt is None:
             raise ValueError("service was constructed without ckpt_dir")
-        t0 = time.perf_counter() if (self.obs is not None
-                                     or self.tracer is not None) else 0.0
-        if step is None:
-            step = max(self.n_ticks, self._ckpt_step + 1)
-        self._ckpt_step = max(self._ckpt_step, step)
-        man = self._manifest()
-        if (self._last_manifest is not None
-                and self._chain_len + 1 < self.compact_every):
-            extra = {"service_delta": {
-                "prev": self._last_man_step,
-                "patch": dict_diff(self._last_manifest, man)}}
-            self._chain_len += 1
-        else:
-            extra = {"service": man}
-            self._chain_len = 0
-        self._last_manifest = man
-        self._last_man_step = step
-        fut = self.ckpt.save(step, self._ckpt_tree(), extra=extra,
-                             keep_last=self.keep_checkpoints,
-                             **self._ckpt_save_kwargs())
-        if self.obs is not None or self.tracer is not None:
-            # the synchronous publish cost: manifest build + device_get
-            # snapshot (the async file write is tracked by ckpt.stall_s)
-            ms = (time.perf_counter() - t0) * 1e3
-            if self.obs is not None:
-                self.obs.histogram("ckpt.publish_ms").observe(ms)
-                self.obs.counter("ckpt.n_checkpoints").inc()
-            if self.tracer is not None:
-                self.tracer.record("ckpt.publish", ms, step=int(step))
-                self.tracer.flush()
+        tr = self.tracer
+        t0 = time.perf_counter() if self.obs is not None else 0.0
+        with maybe_span(tr, "ckpt.publish") as publish:
+            if step is None:
+                step = max(self.n_ticks, self._ckpt_step + 1)
+            self._ckpt_step = max(self._ckpt_step, step)
+            man = self._manifest()
+            if (self._last_manifest is not None
+                    and self._chain_len + 1 < self.compact_every):
+                extra = {"service_delta": {
+                    "prev": self._last_man_step,
+                    "patch": dict_diff(self._last_manifest, man)}}
+                self._chain_len += 1
+            else:
+                extra = {"service": man}
+                self._chain_len = 0
+            self._last_manifest = man
+            self._last_man_step = step
+            fut = self.ckpt.save(step, self._ckpt_tree(), extra=extra,
+                                 keep_last=self.keep_checkpoints,
+                                 **self._ckpt_save_kwargs())
+        # the span and the histogram time the synchronous publish:
+        # manifest build + device_get snapshot (the async file write is
+        # tracked by ckpt.stall_s)
+        if tr is not None:
+            publish.set(step=int(step))
+            tr.flush()
+        if self.obs is not None:
+            self.obs.histogram("ckpt.publish_ms").observe(
+                (time.perf_counter() - t0) * 1e3)
+            self.obs.counter("ckpt.n_checkpoints").inc()
         return fut
 
     @classmethod

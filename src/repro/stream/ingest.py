@@ -69,6 +69,10 @@ RETRYING = "retrying"
 FAILED = "failed"
 EXHAUSTED = "exhausted"
 
+# samples the ``ingest.hold_ms`` histogram keeps: its percentiles are
+# exact over the first this-many released events (``repro.obs.Histogram``)
+HOLD_RING = 1 << 16
+
 
 class IngestError(RuntimeError):
     """Unrecoverable ingest failure (retry budget exhausted, bad resume)."""
@@ -433,6 +437,12 @@ class IngestFrontier:
     watermark back (counted + ``on("stall")``) until it produces again.
     If the buffer exceeds ``reorder_capacity`` the oldest events are
     force-emitted past the watermark (counted in ``n_forced``).
+
+    Hold time: each ``pump()`` reads ``clock`` once and keeps the stamp
+    with every event it buffers, and each ``take_ready()`` reads it once
+    more, so ``last_holds_ms`` holds every released event's time in the
+    buffer, from the poll that took it in to its release (ms).
+    ``publish_obs`` adds them to the ``ingest.hold_ms`` histogram.
     """
 
     def __init__(
@@ -445,6 +455,7 @@ class IngestFrontier:
         retry: RetryPolicy | None = None,
         sleep: Callable[[float], None] = time.sleep,
         seed: int = 0,
+        clock: Callable[[], float] = time.perf_counter,
         _resume: dict | None = None,
     ):
         if allowed_lateness < 0 or reorder_capacity < 1:
@@ -479,7 +490,10 @@ class IngestFrontier:
                 raise IngestError(
                     f"resume manifest names sources not provided: "
                     f"{sorted(missing)}")
-        self._heap: list[tuple[tuple, int, SourceEvent]] = []
+        # (ladder key, source index, pump stamp, event)
+        self._heap: list[tuple[tuple, int, float, SourceEvent]] = []
+        self._clock = clock
+        self.last_holds_ms: list[float] = []   # of the last take_ready
         self.emit_floor: int | None = None
         self.n_emitted = 0
         self.n_late_dropped = 0
@@ -518,6 +532,7 @@ class IngestFrontier:
         """One pull round over every live source; buffers (or late-drops)
         the new deliveries.  Returns how many entered the buffer."""
         n_in = 0
+        stamp = self._clock()
         for si, a in enumerate(self.adapters):
             if a.exhausted:                # includes terminal FAILED
                 continue
@@ -553,7 +568,8 @@ class IngestFrontier:
                     a.ack(ev.seq)
                     self.callbacks.emit(kind, a.name, ev.edge, ev.seq)
                     continue
-                heapq.heappush(self._heap, (_ladder_key(ev, si), si, ev))
+                heapq.heappush(self._heap,
+                               (_ladder_key(ev, si), si, stamp, ev))
                 n_in += 1
         return n_in
 
@@ -597,11 +613,13 @@ class IngestFrontier:
     def take_ready(self, limit: int | None = None) -> list[DataEdge]:
         """Pop emit-ready events in merged order: everything at or below
         the release bound, plus forced evictions while the buffer exceeds
-        ``reorder_capacity``.  Advances the emit floor; acks each."""
+        ``reorder_capacity``.  Advances the emit floor; acks each, and
+        sets ``last_holds_ms`` to the released events' holds."""
         wm = self._release_bound()
         out: list[DataEdge] = []
+        stamps: list[float] = []
         while self._heap and (limit is None or len(out) < limit):
-            key, si, ev = self._heap[0]
+            key, si, stamp, ev = self._heap[0]
             forced = len(self._heap) > self.reorder_capacity
             if not forced and (wm is None or ev.ts > wm):
                 break
@@ -614,6 +632,12 @@ class IngestFrontier:
             self.n_emitted += 1
             self.callbacks.emit("event", ev.edge)
             out.append(ev.edge)
+            stamps.append(stamp)
+        if stamps:
+            now = self._clock()
+            self.last_holds_ms = [(now - t) * 1e3 for t in stamps]
+        else:
+            self.last_holds_ms = []
         return out
 
     def drain(self, max_per_source: int = 64) -> list[DataEdge]:
@@ -665,7 +689,9 @@ class IngestFrontier:
         (no ``IngestStats`` construction); counters use ``set_total``
         so a frontier resumed from a checkpoint (which restores its own
         counters from the same manifest the registry restores from)
-        never double-counts.
+        never double-counts.  The holds of the last ``take_ready`` go
+        into the ``ingest.hold_ms`` histogram, once: call it after each
+        ``take_ready`` whose holds should count.
         """
         obs.counter("ingest.n_emitted").set_total(self.n_emitted)
         obs.counter("ingest.n_late_dropped").set_total(self.n_late_dropped)
@@ -686,6 +712,11 @@ class IngestFrontier:
                 obs.gauge("ingest.window_staleness").set(
                     max(0, self.emit_floor - wm))
         obs.gauge("ingest.buffered").set(len(self._heap))
+        if self.last_holds_ms:
+            h = obs.histogram("ingest.hold_ms", ring_size=HOLD_RING)
+            for v in self.last_holds_ms:
+                h.observe(v)
+            self.last_holds_ms = []
 
     # ------------------------------------------------------------------ #
     # checkpoint / resume

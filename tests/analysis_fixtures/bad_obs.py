@@ -21,4 +21,4 @@ def bad_obs_emit(state, x):
 def ok_obs_host(reg: MetricsRegistry, lat_ms: float):
     # untraced host code: emission is exactly where it belongs
     reg.histogram("tick.latency_ms").observe(lat_ms)
-    TR.record("tick.barrier", lat_ms)
+    TR.event("tick.barrier", ms=lat_ms)

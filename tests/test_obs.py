@@ -31,8 +31,9 @@ from repro.core.multi import SlotTickCache
 from repro.core.oracle import DataEdge
 from repro.core.query import QueryGraph
 from repro.obs import (
-    DEFAULT_LATENCY_BUCKETS_MS, Histogram, MetricsRegistry, Tracer,
-    memory_tracer, percentile, summarize_trace, to_prometheus)
+    DEFAULT_LATENCY_BUCKETS_MS, NULL_SPAN, Histogram, MetricsRegistry,
+    Tracer, maybe_span, memory_tracer, percentile, summarize_trace,
+    to_prometheus)
 from repro.obs.summarize import main as summarize_main
 from repro.runtime.service import ContinuousSearchService
 from repro.stream.generator import StreamConfig, synth_traffic_stream
@@ -165,11 +166,17 @@ def test_instrumentation_differential_on_vs_off():
     assert snap["tick.n_edges"] == svc_on.n_edges_ingested
     assert snap["tick.n_matches"] == sum(matches_on.values())
 
-    # every span carries a tick correlation id covering all ticks
+    # every span carries a tick correlation id covering all ticks; one
+    # dispatch span per tick, and no forest span without a forest
     lines = [json.loads(ln) for ln in sink.getvalue().splitlines()]
-    assert {ln["span"] for ln in lines} >= {
-        "tick.forest", "tick.slot_dispatch", "tick.barrier",
-        "tick.deliver", "coalescer.decision"}
+    names = {ln["span"] for ln in lines}
+    assert names >= {
+        "serve.round", "tick", "tick.dispatch", "tick.barrier",
+        "tick.deliver", "tick.readback", "tick.callbacks",
+        "coalescer.decision"}
+    assert not names & {"tick.forest", "tick.slot_dispatch"}
+    assert sum(ln["span"] == "tick.dispatch" for ln in lines) \
+        == svc_on.n_ticks
     assert max(ln["tick"] for ln in lines) == svc_on.n_ticks
 
 
@@ -181,14 +188,18 @@ def test_trace_summarize_cli_roundtrip(tmp_path, capsys):
     with Tracer(str(path)) as tr:
         for _ in range(3):
             tr.next_tick()
-            tr.record("tick.forest", 1.0)
-            tr.record("tick.barrier", 2.0, n_groups=2)
+            with tr.span("tick"):
+                with tr.span("tick.barrier", n_groups=2):
+                    pass
         tr.event("mesh.collectives", gid=0)
 
     s = summarize_trace(str(path))
     assert s["n_ticks"] == 3 and s["n_bad_lines"] == 0
     assert s["spans"]["tick.barrier"]["count"] == 3
-    assert s["spans"]["tick.barrier"]["p50_ms"] == 2.0
+    assert s["spans"]["tick"]["count"] == 3
+    recs = [json.loads(ln) for ln in path.read_text().splitlines()]
+    assert s["spans"]["tick.barrier"]["p50_ms"] == round(percentile(
+        [r["ms"] for r in recs if r["span"] == "tick.barrier"], 0.5), 4)
 
     assert summarize_main(["summarize", str(path)]) == 0
     out = capsys.readouterr().out
@@ -198,12 +209,20 @@ def test_trace_summarize_cli_roundtrip(tmp_path, capsys):
 
 
 def test_tracer_off_costs_nothing_and_memory_sink():
+    # off: one shared no-op span, whatever the name
+    assert maybe_span(None, "a") is maybe_span(None, "b") is NULL_SPAN
+    with maybe_span(None, "a") as s:
+        assert s is NULL_SPAN
+    # on: spans stay in memory until flush
     tr, sink = memory_tracer()
-    tr.record("a", 1.5, k=1)
+    with tr.span("a", k=1):
+        pass
+    assert sink.getvalue() == ""
     tr.close()
     (line,) = sink.getvalue().splitlines()
     d = json.loads(line)
-    assert d["span"] == "a" and d["ms"] == 1.5 and d["k"] == 1
+    assert d["span"] == "a" and d["k"] == 1 and d["parent"] is None
+    assert d["ms"] == (d["end_ns"] - d["start_ns"]) / 1e6 >= 0
 
 
 # ------------------------------------------------------------------ #
