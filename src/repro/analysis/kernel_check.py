@@ -168,25 +168,36 @@ def block_shape_finding(name, array_shape, block_shape, sublane=8):
 
 
 def _compat_specs(n_slots, cap, cbp, ta, tb, widths, a_batched, b_batched,
-                  max_new=None):
-    """The 3-D-grid ``(slot, A-tile, B-tile)`` specs of both compat_join
-    kernels, mirrored from ``kernel._grid_spec`` and the out_specs:
-    ``(name, array_shape, block_shape, index_map, sublane)``.  Packed
-    operands are ``[S?, K, C]`` (rows on lanes); a per-slot operand has
-    a squeezed slot dim, a shared one ignores the slot coordinate."""
+                  max_new=None, blocks=(1, 1), ext=None):
+    """The ``(slot, A-block, B-block)`` grid and specs of both compat_join
+    kernels, mirrored from ``kernel._side_spec`` and the out_specs:
+    ``(grid, [(name, array_shape, block_shape, index_map, sublane)])``.
+    Packed operands are ``[S?, n_tiles, K, tile]`` with ``blocks`` =
+    (A, B) tiles per block (one for the mask kernel); a per-slot operand
+    has a squeezed slot dim, a shared one ignores the slot coordinate.
+    The pairs kernel clamps a block index to the last block below the
+    extent, ``ext`` (A, B) live tiles (None: the whole side)."""
     from repro.kernels.compat_join.kernel import out_rows
     nva, nea, nvb, neb = widths
     ka, kb = nva + nea + 1, nvb + neb + 1
-    specs = []
-    for name, k, c, t, batched, idx in (
-            ("a", ka, cap, ta, a_batched, lambda i, j: i),
-            ("b", kb, cbp, tb, b_batched, lambda i, j: j)):
+    specs, grid = [], [n_slots]
+    for name, k, c, t, batched, blk, e, pick in (
+            ("a", ka, cap, ta, a_batched, blocks[0], ext and ext[0],
+             lambda i, j: i),
+            ("b", kb, cbp, tb, b_batched, blocks[1], ext and ext[1],
+             lambda i, j: j)):
+        n = c // t
+        grid.append(n // blk)
+        last = (n if e is None else max(-(-e // blk) - 1, 0))
+
+        def idx(i, j, pick=pick, last=last):
+            return min(pick(i, j), last)
         if batched:
-            specs.append((name, (n_slots, k, c), (None, k, t),
-                          lambda s, i, j, idx=idx: (s, 0, idx(i, j)), 8))
+            specs.append((name, (n_slots, n, k, t), (None, blk, k, t),
+                          lambda s, i, j, idx=idx: (s, idx(i, j), 0, 0), 8))
         else:
-            specs.append((name, (k, c), (k, t),
-                          lambda s, i, j, idx=idx: (0, idx(i, j)), 8))
+            specs.append((name, (n, k, t), (blk, k, t),
+                          lambda s, i, j, idx=idx: (idx(i, j), 0, 0), 8))
     if max_new is None:
         specs.append(("mask_out", (n_slots, cap, cbp), (None, ta, tb),
                        lambda s, i, j: (s, i, j), 32))      # int8
@@ -197,7 +208,7 @@ def _compat_specs(n_slots, cap, cbp, ta, tb, widths, a_batched, b_batched,
                           lambda s, i, j: (s, 0, 0), 8))
         specs.append(("n_out", (n_slots, 1, _LANE), (None, 1, _LANE),
                       lambda s, i, j: (s, 0, 0), 8))
-    return specs
+    return tuple(grid), specs
 
 
 def check_tiles_and_bounds(fast: bool = False) -> list[Finding]:
@@ -223,14 +234,21 @@ def check_tiles_and_bounds(fast: bool = False) -> list[Finding]:
                     f"padded caps ({cap},{cbp}) not exact multiples of "
                     f"tiles ({ta_k},{tb}) or empty grid"))
                 continue
-            grid = (cap // ta_k, cbp // tb)
-            for n_slots, flags in itertools.product(
-                    SLOTS if not fast else SLOTS[:2],
+            n_a, n_b = cap // ta_k, cbp // tb
+            # the mask kernel's one tile per block; the pairs kernel's
+            # whole sides, gridded A, gridded both, at extents 0, 1, all
+            layouts = [((1, 1), None)]
+            if max_new is not None:
+                layouts = [(blk, ext)
+                           for blk in ((n_a, n_b), (1, n_b), (1, 1))
+                           for ext in (None, (0, 0), (1, 1))]
+            for (blocks, ext), n_slots, flags in itertools.product(
+                    layouts, SLOTS if not fast else SLOTS[:2],
                     FLAG_SETS if not fast else FLAG_SETS[:2]):
-                specs = _compat_specs(n_slots, cap, cbp, ta_k, tb, widths,
-                                      *flags, max_new=max_new)
-                bad += _bounds_ok((n_slots,) + grid,
-                                  [sp[:4] for sp in specs])
+                grid, specs = _compat_specs(
+                    n_slots, cap, cbp, ta_k, tb, widths, *flags,
+                    max_new=max_new, blocks=blocks, ext=ext)
+                bad += _bounds_ok(grid, [sp[:4] for sp in specs])
                 refused += [f for sp in specs
                             if (f := block_shape_finding(
                                 f"{sym}.{sp[0]}", sp[1], sp[2], sp[4]))]

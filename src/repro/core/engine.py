@@ -58,16 +58,18 @@ NO_WATERMARK = int(np.iinfo(np.int32).min)
 class TickLoad(NamedTuple):
     """Live-row counters of one tick, as the host reads them back.
 
-    They are sums of validity masks the tick already holds, beside the
-    static row counts they are out of.  Tables are counted after expiry;
-    each ``join_pairs`` call counts the rows it joins on side A and side
-    B against the rows it sweeps there, so ``Σ live_a·live_b / Σ
-    cap_a·cap_b`` is the share of visited pairs that can match.  The
-    tick returns them packed in ONE int32 vector (``TickResult.load``,
-    ``[..., 4 + 4 * n_joins]``), so a group's counters come back in one
-    transfer; ``unpack`` reads it, with any leading slot axes.  Under a
-    capacity-sharded tick (``axis_name``) the vector is psum'd over the
-    shards."""
+    They are reductions of validity masks the tick already holds, beside
+    the static row counts they are out of.  Tables are counted after
+    expiry; each ``join_pairs`` call counts the rows it joins on side A
+    and side B, the rows below each side's live extent (the last live
+    row + 1), and the rows it holds there, so ``Σ live_a·live_b / Σ
+    cap_a·cap_b`` is the share of a whole sweep's pairs that can match
+    and ``Σ ext_a·ext_b / Σ cap_a·cap_b`` the share the pairs kernel
+    sweeps.  The tick returns them packed in ONE int32 vector
+    (``TickResult.load``, ``[..., 4 + 6 * n_joins]``), so a group's
+    counters come back in one transfer; ``unpack`` reads it, with any
+    leading slot axes.  Under a capacity-sharded tick (``axis_name``)
+    the vector is psum'd over the shards."""
 
     level_live: np.ndarray    # live rows, all level tables
     level_cap: np.ndarray     # their capacity
@@ -75,23 +77,27 @@ class TickLoad(NamedTuple):
     l0_cap: np.ndarray        # their capacity
     join_live_a: np.ndarray   # [..., n_joins]: live rows, side A
     join_live_b: np.ndarray   # [..., n_joins]: live rows, side B
-    join_cap_a: np.ndarray    # [..., n_joins]: rows swept, side A
-    join_cap_b: np.ndarray    # [..., n_joins]: rows swept, side B
+    join_ext_a: np.ndarray    # [..., n_joins]: rows below the extent, A
+    join_ext_b: np.ndarray    # [..., n_joins]: rows below the extent, B
+    join_cap_a: np.ndarray    # [..., n_joins]: rows held, side A
+    join_cap_b: np.ndarray    # [..., n_joins]: rows held, side B
 
     @classmethod
     def unpack(cls, packed) -> "TickLoad":
         p = np.asarray(packed, np.int64)
-        j = (p.shape[-1] - 4) // 4
+        j = (p.shape[-1] - 4) // 6
         return cls(p[..., 0], p[..., 1], p[..., 2], p[..., 3],
-                   *(p[..., 4 + k * j:4 + (k + 1) * j] for k in range(4)))
+                   *(p[..., 4 + k * j:4 + (k + 1) * j] for k in range(6)))
 
-    def totals(self) -> tuple[int, int, int, int]:
-        """(live rows, capacity rows, live pairs, capacity pairs), summed
-        over every slot and join; the pair products are exact int64."""
+    def totals(self) -> tuple[int, int, int, int, int]:
+        """(live rows, capacity rows, live pairs, capacity pairs, swept
+        pairs), summed over every slot and join; the pair products are
+        exact int64."""
         return (int(self.level_live.sum() + self.l0_live.sum()),
                 int(self.level_cap.sum() + self.l0_cap.sum()),
                 int((self.join_live_a * self.join_live_b).sum()),
-                int((self.join_cap_a * self.join_cap_b).sum()))
+                int((self.join_cap_a * self.join_cap_b).sum()),
+                int((self.join_ext_a * self.join_ext_b).sum()))
 
 
 class TickResult(NamedTuple):
@@ -100,7 +106,7 @@ class TickResult(NamedTuple):
     match_bindings: jnp.ndarray   # int32 [max_out, nv_total]
     match_ets: jnp.ndarray        # int32 [max_out, ne_total]
     match_valid: jnp.ndarray      # bool  [max_out]
-    load: jnp.ndarray             # int32 [4 + 4*n_joins]: ``TickLoad``
+    load: jnp.ndarray             # int32 [4 + 6*n_joins]: ``TickLoad``
 
 
 class _View(NamedTuple):
@@ -180,26 +186,31 @@ def _compact(view: _View, mask, size: int):
 def _pack_load(levels, l0, joins: list[tuple]) -> jnp.ndarray:
     """The tick's packed ``TickLoad`` from its post-expiry tables and the
     ``(valid_a, valid_b)`` masks of each ``join_pairs`` call.  Masks of
-    one length are summed in one reduction."""
+    one length are reduced together: one sum, and one max of the live
+    rows' positions + 1 (the extent)."""
     tables = [t.valid for sub in levels for t in sub]
     l0v = [t.valid for t in l0]
     masks = tables + l0v + [m for pair in joins for m in pair]
     sums: list = [None] * len(masks)
+    exts: list = [None] * len(masks)
     by_len: dict[int, list[int]] = {}
     for i, m in enumerate(masks):
         by_len.setdefault(m.shape[-1], []).append(i)
-    for idx in by_len.values():
-        s = jnp.sum(jnp.stack([masks[i] for i in idx]), axis=-1, dtype=I32)
+    for n, idx in by_len.items():
+        stacked = jnp.stack([masks[i] for i in idx])
+        s = jnp.sum(stacked, axis=-1, dtype=I32)
+        e = jnp.max(jnp.where(stacked, jnp.arange(1, n + 1, dtype=I32), 0),
+                    axis=-1)
         for k, i in enumerate(idx):
-            sums[i] = s[k]
+            sums[i], exts[i] = s[k], e[k]
     zero = jnp.zeros((), I32)
     nt, nl = len(tables), len(l0v)
     head = [sum(sums[:nt], zero), sum(m.shape[-1] for m in tables),
             sum(sums[nt:nt + nl], zero), sum(m.shape[-1] for m in l0v)]
-    pairs = sums[nt + nl:]
+    pairs, ext = sums[nt + nl:], exts[nt + nl:]
     return jnp.stack(
         [jnp.asarray(x, I32) for x in head]
-        + pairs[0::2] + pairs[1::2]
+        + pairs[0::2] + pairs[1::2] + ext[0::2] + ext[1::2]
         + [jnp.asarray(a.shape[-1], I32) for a, _ in joins]
         + [jnp.asarray(b.shape[-1], I32) for _, b in joins])
 

@@ -8,18 +8,25 @@ Responsibilities:
   reuse the *identical* static kernel key instead of rebuilding nested
   tuples per tick.
 * **Packing, tiling + padding** — each side's (bind, ets, valid) is
-  packed into one transposed int32 operand (table rows on lanes; see
-  ``kernel``), tile sizes come from ``kernel.choose_tiles``
+  packed into one int32 operand of ``[K, tile]`` tiles (table rows on
+  lanes; see ``kernel``), tile sizes come from ``kernel.choose_tiles``
   (shape-derived), and the capacity axes are padded to tile multiples
   with ``valid=0`` rows that never match.
+* **Live extents** — for ``compat_join_pairs``, each side's extent per
+  slot (the tiles up to and including the last tile with a valid row)
+  is reduced from the packed operand's validity row and passed to the
+  kernel as scalar prefetch, so the kernel sweeps the live tiles and not
+  the capacity; a side shared by every slot has one extent, broadcast.
+  Whether a side is one whole-axis VMEM block or stays on the grid is
+  chosen from its shape (``kernel.sweep_blocks``).
 * **Batched (vmapped) dispatch** — each op is wrapped in
   ``jax.custom_batching.custom_vmap``: an unvmapped call is the
-  one-slot case of the kernel's ``(slot, A-tile, B-tile)`` grid, while
+  one-slot case of the kernel's ``(slot, A-block, B-block)`` grid, while
   a vmapped call (the slot ticks of ``repro.core.multi``) lowers to ONE
   such kernel for the whole slot group — one ``pallas_call`` per join,
   with per-slot traced windows.  A side shared across slots (e.g. the
   slot tick's stream edges in a prefix-node join) is NOT broadcast: it
-  stays 2-D and the kernel's index_map ignores the slot grid dim.
+  has no slot axis and the kernel's index_map ignores the slot grid dim.
 * **Traced window** — ``window`` is passed to the kernel as a
   scalar-prefetch input; changing it (or any slot's window) never
   recompiles.  Only *whether* a window predicate exists is static.
@@ -33,8 +40,9 @@ Ops:
                           ``core.join.extract_pairs`` with no [CA, CB]
                           mask materialized in HBM.  Pairs are emitted
                           in tile order: same pair SET and exact
-                          ``n_dropped``; the keep-subset under overflow
-                          is backend-defined.
+                          ``n_dropped``; under overflow the keep-subset
+                          is the tile-order prefix (``extract_pairs``
+                          keeps a row-major one).
 """
 
 from __future__ import annotations
@@ -95,9 +103,10 @@ def _as_window(window):
     return jnp.asarray(window, jnp.int32).reshape(())
 
 
-def _pack_side(bind, ets, valid, flags, n_slots, cap):
-    """One packed, transposed int32 operand ``[S?, nv + ne + 1, cap]``,
-    padded with ``valid = 0`` rows.
+def _pack_side(bind, ets, valid, flags, n_slots, tile):
+    """One packed int32 operand ``[S?, n_tiles, nv + ne + 1, tile]``,
+    padded with ``valid = 0`` rows: table rows on lanes within a tile,
+    the validity flag the last row.
 
     The side carries the slot axis if any of its three parts does; the
     parts that don't are broadcast to it (only the narrow columns of a
@@ -109,7 +118,19 @@ def _pack_side(bind, ets, valid, flags, n_slots, cap):
         parts = [x if f else jnp.broadcast_to(x, (n_slots,) + x.shape)
                  for x, f in zip(parts, flags)]
     packed = jnp.concatenate([x.astype(jnp.int32) for x in parts], axis=-1)
-    return _pad_to(jnp.swapaxes(packed, -1, -2), cap, axis=-1), batched
+    c, k = packed.shape[-2:]
+    packed = _pad_to(packed, _ceil_to(max(c, 1), tile), axis=-2)
+    tiles = packed.reshape(packed.shape[:-2] + (-1, tile, k))
+    return jnp.swapaxes(tiles, -1, -2), batched
+
+
+def _extent(side, n_slots):
+    """int32 ``[n_slots]``: tiles up to and including the last tile with
+    a valid row (0 if none), from the validity row of a packed side."""
+    live = jnp.any(side[..., -1, :] > 0, axis=-1)          # [S?, n_tiles]
+    pos = jnp.arange(1, live.shape[-1] + 1, dtype=jnp.int32)
+    ext = jnp.max(jnp.where(live, pos, 0), axis=-1)
+    return jnp.broadcast_to(ext, (n_slots,))
 
 
 def _prep(args, in_batched, n_slots, mask):
@@ -123,11 +144,10 @@ def _prep(args, in_batched, n_slots, mask):
     ta, tb = K.choose_tiles(ca, cb)
     if mask:
         ta = K.mask_tile_a(ta)
-    cap, cbp = _ceil_to(max(ca, 1), ta), _ceil_to(max(cb, 1), tb)
     a, a_batched = _pack_side(bind_a, ets_a, valid_a, in_batched[0:3],
-                              n_slots, cap)
+                              n_slots, ta)
     b, b_batched = _pack_side(bind_b, ets_b, valid_b, in_batched[3:6],
-                              n_slots, cbp)
+                              n_slots, tb)
     window = jnp.broadcast_to(window, (n_slots,))
     kw = dict(widths=(bind_a.shape[-1], ets_a.shape[-1],
                       bind_b.shape[-1], ets_b.shape[-1]),
@@ -181,9 +201,11 @@ def compat_mask(bind_a, ets_a, valid_a, bind_b, ets_b, valid_b, rel, trel,
 def _pairs_op(rel, trel, max_new, has_window, interpret):
     def run(args, in_batched, n_slots):
         window, a, b, kw, _, _ = _prep(args, in_batched, n_slots, False)
+        blk_a, blk_b = K.sweep_blocks(a.shape, b.shape)
         a_idx, b_idx, n_total = K.compat_join_pairs_kernel(
-            window, a, b, rel=rel, trel=trel, has_window=has_window,
-            max_new=max_new, interpret=interpret, **kw)
+            window, _extent(a, n_slots), _extent(b, n_slots), a, b,
+            rel=rel, trel=trel, has_window=has_window, blk_a=blk_a,
+            blk_b=blk_b, max_new=max_new, interpret=interpret, **kw)
         return (a_idx.reshape(n_slots, -1)[:, :max_new],
                 b_idx.reshape(n_slots, -1)[:, :max_new],
                 n_total[:, 0, 0])
@@ -208,8 +230,11 @@ def compat_join_pairs(bind_a, ets_a, valid_a, bind_b, ets_b, valid_b,
 
     Returns ``(a_idx, b_idx, pair_valid, n_dropped)`` with the same
     contract as ``core.join.extract_pairs`` applied to the mask, except
-    that pairs are emitted in tile order (set semantics; ``n_dropped``
-    is exact, the keep-subset under overflow is backend-defined).
+    that pairs are emitted in tile order, A-tile major (set semantics;
+    ``n_dropped`` is exact, and under overflow the first ``max_new`` in
+    tile order are kept).  Only the tiles below each side's live extent
+    are swept; the rest hold no match, so the output is that of a sweep
+    over every tile.
     """
     rel_tt, trel_tt = normalize_spec(rel, trel)
     op = _pairs_op(rel_tt, trel_tt, int(max_new), window is not None,

@@ -170,11 +170,13 @@ class ServeInfo(NamedTuple):
     # live-row counters of this tick (``repro.core.engine.TickLoad``),
     # summed over every slot the tick ran: live and allocated rows of
     # the level and L0 tables after expiry, and over every join call
-    # Σ live_a·live_b against Σ cap_a·cap_b (the pairs a kernel visits)
+    # Σ live_a·live_b and Σ ext_a·ext_b (the pairs below both sides'
+    # live extents, which the pairs kernel sweeps) against Σ cap_a·cap_b
     live_rows: int = 0
     capacity_rows: int = 0
     live_pairs: int = 0
     capacity_pairs: int = 0
+    swept_pairs: int = 0
     # ``serve_frontier`` only: each released record's hold in the
     # frontier's reorder buffer, ms (``IngestFrontier.take_ready``)
     hold_ms: tuple = ()
@@ -667,7 +669,7 @@ class ContinuousSearchService:
                 deliver.set(n_matches=n_matches)
                 tick_span.set(chunk=len(chunk), live_rows=load[0],
                               capacity_rows=load[1], live_pairs=load[2],
-                              capacity_pairs=load[3])
+                              capacity_pairs=load[3], swept_pairs=load[4])
         self.n_ticks += 1
         self.n_edges_ingested += len(chunk)
         obs = self.obs
@@ -686,16 +688,16 @@ class ContinuousSearchService:
             chunk=len(chunk), latency_ms=lat_ms, n_overflow=tick_overflow,
             n_shared_prefix_ticks=len(views), live_rows=load[0],
             capacity_rows=load[1], live_pairs=load[2],
-            capacity_pairs=load[3])
+            capacity_pairs=load[3], swept_pairs=load[4])
 
     def _deliver(self, results, on_match, totals: dict
-                 ) -> tuple[int, int, tuple[int, int, int, int]]:
+                 ) -> tuple[int, int, tuple[int, int, int, int, int]]:
         """Read back each group's tick result and deliver its matches.
         Returns (new matches, overflow, ``TickLoad.totals()`` summed over
         the groups)."""
         tr = self.tracer
         n_matches = tick_overflow = 0
-        load = (0, 0, 0, 0)
+        load = (0, 0, 0, 0, 0)
         for g, res in results:
             armed = [(k, qid) for k, qid in enumerate(g.qids)
                      if qid is not None]

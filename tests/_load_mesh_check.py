@@ -22,7 +22,8 @@ from repro.runtime import (  # noqa: E402
 
 from test_tick_trace import BATCH, CAP, chain2, split_query, stream  # noqa: E402
 
-FIELDS = ("live_rows", "capacity_rows", "live_pairs", "capacity_pairs")
+FIELDS = ("live_rows", "capacity_rows", "live_pairs", "capacity_pairs",
+          "swept_pairs")
 
 
 def serve(svc, edges, on_tick=None):

@@ -6,7 +6,9 @@ that is described, not attached.  It refuses what interpret mode and the
 ``repro.analysis`` KC rules cannot see (block shapes, layouts, scalar
 stores to VMEM), so these compiles guard every change to the kernels and
 the slot tick at the widths ``chip_smoke.py`` serves: tables of 16,384
-rows joined against 1,024-edge batches, 64 slots per group.
+rows joined against 1,024-edge batches, 64 slots per group; and the L0
+delta joins of the ``netflow-w1`` benchmark deployment, whose 16,384-row
+sides the pairs kernel holds whole in VMEM.
 
 The topology is described inside a module fixture, never at import: one
 process at a time may load the TPU library, so only the test worker that
@@ -101,6 +103,34 @@ def test_kernel_compiles_stacked(one_chip, op):
             ba, ea, va, bb, eb, vb, rel, trel, MAX_NEW, window=w)
     fn = jax.vmap(one, in_axes=(0, 0, 0, None, None, 0, 0))
     assert "tpu_custom_call" in _compiled_text(fn, *a, *b, vb, win)
+
+
+@pytest.mark.parametrize("ca,cb", [(MAX_NEW, CA), (CA, MAX_NEW),
+                                   (4 * CA, MAX_NEW), (MAX_NEW, 4 * CA)],
+                         ids=["delta_a", "delta_b", "grid_a", "grid_both"])
+def test_pairs_kernel_compiles_for_l0_joins(one_chip, ca, cb):
+    """The L0 delta joins ΔA ⋈ B and A_old ⋈ ΔB, both sides per slot,
+    at the widest operands of the ``netflow-w1`` walks (12 packed rows
+    on A, 6 on B): each side is one whole-axis block of the kernel.  A
+    65,536-row side exceeds ``kernel.BLOCK_BYTES`` and stays on the grid
+    (a gridded B grids A too)."""
+    nva, nea, nvb, neb = 6, 5, 3, 2
+    rel = np.zeros((nva, nvb), bool)
+    rel[0, 0] = True
+    trel = np.zeros((nea, neb), np.int8)
+    trel[-1, 0] = -1
+
+    def s(*shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct((SLOTS,) + shape, dtype,
+                                    sharding=one_chip)
+
+    one = lambda ba, ea, va, bb, eb, vb, w: cj_ops.compat_join_pairs(
+        ba, ea, va, bb, eb, vb, rel, trel, MAX_NEW, window=w)
+    fn = jax.vmap(one)
+    text = _compiled_text(fn, s(ca, nva), s(ca, nea), s(ca, dtype=jnp.bool_),
+                          s(cb, nvb), s(cb, neb), s(cb, dtype=jnp.bool_),
+                          s())
+    assert "tpu_custom_call" in text
 
 
 def _c2_query() -> QueryGraph:

@@ -1,6 +1,10 @@
 """compat_join Pallas kernels vs pure-jnp oracle: shape/dtype/spec sweep,
 traced windows, vmapped slot-group batching, and the fused pair-extraction
-op (interpret mode executes the kernel bodies on CPU)."""
+op (interpret mode executes the kernel bodies on CPU).  The pairs kernel
+sweeps only the tiles below each side's live extent: its output must
+equal, element for element, a NumPy emulation of tile-order emission
+over the whole padded grid, on sparse validity layouts, per-slot
+extents, and with each side held whole in VMEM or kept on the grid."""
 
 import numpy as np
 import pytest
@@ -14,6 +18,7 @@ from repro.core.join import JoinBackend, compat_mask_ref, extract_pairs
 from repro.core.query import QueryGraph
 from repro.core.state import init_state, make_batch
 from repro.kernels.compat_join import ops as cj_ops
+from repro.kernels.compat_join import kernel as cj_kernel
 from repro.kernels.compat_join import ref as cj_ref
 from repro.kernels.compat_join.kernel import TILE_A, TILE_B, choose_tiles
 from repro.stream.generator import StreamConfig, synth_traffic_stream, to_batches
@@ -166,12 +171,40 @@ def _pair_set(a_idx, b_idx, valid):
     return set(zip(a[v].tolist(), b[v].tolist()))
 
 
+def tile_order_pairs(mask, max_new):
+    """NumPy emulation of the pairs kernel sweeping the WHOLE padded grid:
+    every (A-tile, B-tile), A-tile major, row-major inside a tile, the
+    first ``max_new`` pairs kept.  Returns ``(a_idx, b_idx, pair_valid,
+    n_dropped)`` as ``compat_join_pairs`` does (unused entries 0)."""
+    m = np.asarray(mask)
+    ta, tb = choose_tiles(*m.shape)
+    a, b = [], []
+    for i in range(0, m.shape[0], ta):
+        for j in range(0, m.shape[1], tb):
+            r, c = np.nonzero(m[i:i + ta, j:j + tb])
+            a += (r + i).tolist()
+            b += (c + j).tolist()
+    keep = min(len(a), max_new)
+    out = np.zeros((3, max_new), np.int32)
+    out[0, :keep], out[1, :keep], out[2, :keep] = a[:keep], b[:keep], 1
+    return out[0], out[1], out[2].astype(bool), max(len(a) - max_new, 0)
+
+
+def _assert_tile_order(got, mask, max_new):
+    """The kernel's pairs equal the full sweep's, element for element."""
+    want = tile_order_pairs(mask, max_new)
+    for g, w in zip(got[:3], want[:3]):
+        np.testing.assert_array_equal(np.asarray(g), w)
+    assert int(got[3]) == want[3]
+
+
 def _check_pairs_vs_oracle(args, max_new):
     want_mask = compat_mask_ref(*args[:6], args[6], args[7], args[8])
     wa, wb, wv, wd = extract_pairs(want_mask, max_new)
     ga, gb, gv, gd = cj_ops.compat_join_pairs(
         *args[:6], args[6], args[7], max_new, args[8], interpret=True)
     assert int(gd) == int(wd), "n_dropped must be exact"
+    _assert_tile_order((ga, gb, gv, gd), want_mask, max_new)
     want_set = _pair_set(wa, wb, wv)
     got_set = _pair_set(ga, gb, gv)
     if int(wd) == 0:
@@ -218,6 +251,126 @@ def test_fused_pairs_vmapped_slot_group():
             full = set(zip(*(x.tolist()
                              for x in np.nonzero(np.asarray(mask)))))
             assert _pair_set(ga[s], gb[s], gv[s]) <= full
+
+
+# --------------------------------------------------------------------- #
+# Live extents: sparse validity layouts, per-slot extents, VMEM blocks.
+# --------------------------------------------------------------------- #
+def live_rows(rng, c, layout):
+    """Validity of a ``c``-row side laid out as tables and deltas are:
+    ``none`` (no live row), ``dense`` (80% live), ``prefix`` (all live
+    below a random extent), ``holes`` (half live below it), ``last``
+    (one live row, in the last tile), ``edge`` (rows 255 and 256, either
+    side of a tile boundary)."""
+    v = np.zeros(c, bool)
+    ext = int(rng.integers(1, c + 1))
+    if layout == "dense":
+        v = rng.random(c) < 0.8
+    elif layout == "prefix":
+        v[:ext] = True
+    elif layout == "holes":
+        v[:ext] = rng.random(ext) < 0.5
+        v[ext - 1] = True
+    elif layout == "last":
+        v[c - 1 - int(rng.integers(0, c % TILE_A or TILE_A))] = True
+    elif layout == "edge":
+        v[[255, 256]] = True
+    return jnp.asarray(v)
+
+
+def _sparse_case(seed, layout_a, layout_b, ca=600, cb=700):
+    """600 x 700 rows: 3 x 3 tiles of 256, the last ones partial.  Vertex
+    bindings are distinct, so most live pairs match: those whose A's
+    last edge precedes B's edge (A's timestamps 0-29, B's 15-44)."""
+    rng = np.random.default_rng(seed)
+    args = list(rand_case(rng, ca, cb, 4, 2, 4, 1, None))
+    args[0] = jnp.asarray(rng.permutation(ca * 4).reshape(ca, 4), jnp.int32)
+    args[3] = jnp.asarray(ca * 4 + rng.permutation(cb * 2).reshape(cb, 2),
+                          jnp.int32)
+    args[4] = args[4] + 15
+    args[2], args[5] = live_rows(rng, ca, layout_a), live_rows(rng, cb,
+                                                               layout_b)
+    args[6] = np.zeros((4, 2), bool)
+    args[7] = np.zeros((4, 1), np.int8)
+    args[7][-1, 0] = -1
+    return tuple(args)
+
+
+def _block_budget(mode, kb=4):
+    """``kernel.BLOCK_BYTES`` that holds both 3-tile sides whole
+    (``whole``), only B (``grid_a``: A stays on the grid), or neither
+    (``grid_both``); B packs ``kb`` rows, padded to 8."""
+    b_bytes = 8 * TILE_B * 3 * 4
+    return {"whole": cj_kernel.BLOCK_BYTES, "grid_a": b_bytes,
+            "grid_both": 0}[mode]
+
+
+SPARSE = [("none", "dense"), ("dense", "none"), ("prefix", "prefix"),
+          ("holes", "holes"), ("last", "dense"), ("dense", "last"),
+          ("edge", "edge"), ("holes", "last")]
+
+
+@pytest.mark.parametrize("mode", ["whole", "grid_a", "grid_both"])
+@pytest.mark.parametrize("layout_a,layout_b", SPARSE)
+def test_fused_pairs_sparse_layouts(monkeypatch, layout_a, layout_b, mode):
+    """Pairs over tiles below the extents equal the full sweep's, bit
+    for bit, with and without overflow (max_new 3 keeps a prefix)."""
+    monkeypatch.setattr(cj_kernel, "BLOCK_BYTES", _block_budget(mode))
+    args = _sparse_case(len(layout_a) * 7 + len(layout_b), layout_a,
+                        layout_b)
+    mask = compat_mask_ref(*args[:6], args[6], args[7], args[8])
+    for max_new in (3, 4096):
+        _check_pairs_vs_oracle(args, max_new)
+    if layout_a != "none" and layout_b != "none":
+        assert np.asarray(mask).any()
+
+
+def test_extent_counts_tiles_to_the_last_live_one():
+    """``ops._extent``: 0 for no live row, else the tiles up to and
+    including the last tile with a valid row (padding never counts)."""
+    rng = np.random.default_rng(0)
+    for layout, want in (("none", 0), ("edge", 2), ("last", 3)):
+        v = live_rows(rng, 600, layout)
+        side, _ = cj_ops._pack_side(jnp.zeros((600, 2), jnp.int32),
+                                    jnp.zeros((600, 1), jnp.int32), v,
+                                    (False,) * 3, 1, TILE_A)
+        assert side.shape == (3, 4, TILE_A)
+        assert cj_ops._extent(side, 2).tolist() == [want, want]
+
+
+@pytest.mark.parametrize("mode", ["whole", "grid_both"])
+@pytest.mark.parametrize("shared", ["b", "a", "neither"])
+def test_fused_pairs_vmapped_slot_extents(monkeypatch, shared, mode):
+    """A vmapped slot group whose slots have different extents on the
+    per-slot side (none, one tile, holes, all three tiles), against a
+    side shared by every slot (2-D, one extent) or per slot too: each
+    slot equals the full sweep of its own mask, overflow included."""
+    monkeypatch.setattr(cj_kernel, "BLOCK_BYTES", _block_budget(mode))
+    rng = np.random.default_rng(17)
+    ca, cb, max_new = 600, 700, 5
+    ba, ea, _, bb, eb, _, rel, trel, _ = _sparse_case(17, "none", "none")
+    per_slot = [live_rows(rng, 600, x)
+                for x in ("none", "edge", "holes", "dense")]
+    va_s = jnp.stack(per_slot)
+    vb_s = jnp.stack([live_rows(rng, cb, x)
+                      for x in ("dense", "holes", "none", "last")])
+    va1, vb1 = live_rows(rng, ca, "holes"), live_rows(rng, cb, "prefix")
+    ws = jnp.asarray([30, 12, 30, 30], jnp.int32)
+    in_a = None if shared == "a" else 0
+    in_b = None if shared == "b" else 0
+    va = va1 if shared == "a" else va_s
+    vb = vb1 if shared == "b" else vb_s
+    fn = jax.jit(jax.vmap(
+        lambda xa, xb, w: cj_ops.compat_join_pairs(
+            ba, ea, xa, bb, eb, xb, rel, trel, max_new, w, interpret=True),
+        in_axes=(in_a, in_b, 0)))
+    got = fn(va, vb, ws)
+    for s in range(4):
+        xa = va if in_a is None else va[s]
+        xb = vb if in_b is None else vb[s]
+        mask = compat_mask_ref(ba, ea, xa, bb, eb, xb, rel, trel,
+                               int(ws[s]))
+        _assert_tile_order([x[s] for x in got], mask, max_new)
 
 
 def test_spec_normalization_is_cached():

@@ -6,9 +6,10 @@
   the ``repro.*`` annotations in the profile: one ``serve.round`` per
   round, with ``tick`` -> ``tick.dispatch`` / ``tick.barrier`` /
   ``tick.deliver`` -> ``tick.readback`` / ``tick.callbacks`` inside.
-* ``TickLoad``: the live-row and live-pair counters equal counts taken
-  on the host from the slot states before and after each tick (REF
-  backend, small tables), and ``ServeInfo`` carries their totals; on 4
+* ``TickLoad``: the live-row, live-pair and extent counters equal
+  counts taken on the host from the slot states before and after each
+  tick (REF backend, small tables), every tick sweeps at least its live
+  pairs, and ``ServeInfo`` carries their totals; on 4
   virtual devices ``ShardedSearchService`` reports the same totals, and
   its psum'd ``MeshTickStats`` agree (subprocess).
 * ``ingest.hold_ms``: exact holds on a ``ScriptedSource`` with a
@@ -169,6 +170,7 @@ def test_profile_holds_the_span_tree(tmp_path):
     ticks = [s for s in spans if s["span"] == "tick"]
     assert [s["live_rows"] for s in ticks] == [i.live_rows for i in infos]
     assert [s["live_pairs"] for s in ticks] == [i.live_pairs for i in infos]
+    assert [s["swept_pairs"] for s in ticks] == [i.swept_pairs for i in infos]
 
 
 # ------------------------------------------------------------------ #
@@ -189,27 +191,33 @@ def _label_mask(batch, params, k):
 def host_load(plan, pre, post, batch, k):
     """Slot ``k``'s counters from the states before and after a tick.
     A table's rows before expiry are those valid before the tick plus
-    those appended in it (its ``fresh`` rows afterwards)."""
-    n = lambda x: int(np.sum(np.asarray(x)[k]))
+    those appended in it (its ``fresh`` rows afterwards); a compacted
+    delta holds its rows first.  Each join is ``(live_a, live_b, ext_a,
+    ext_b, cap_a, cap_b)``, an extent being the last live row + 1."""
+    row = lambda x: np.asarray(x)[k]
+    n = lambda x: int(np.sum(row(x)))
     cap = lambda t: np.asarray(t.valid).shape[1]
-    appended = lambda a, b: n(a.valid) + n(b.fresh)
+    ext = lambda m: int(np.flatnonzero(m)[-1]) + 1 if np.any(m) else 0
+    held = lambda a, b: row(a.valid) | row(b.fresh)    # before expiry
     em = _label_mask(batch, pre.params, k)
     lv_pre, lv_post = pre.engines.levels, post.engines.levels
     tables = [t for sub in lv_post for t in sub]
     joins = []
     for si, s in enumerate(plan.subqueries):
         for li in range(1, len(s.levels)):
-            joins.append((appended(lv_pre[si][li - 1], lv_post[si][li - 1]),
-                          int(em[s.levels[li].qedge].sum()),
+            a = held(lv_pre[si][li - 1], lv_post[si][li - 1])
+            b = em[s.levels[li].qedge]
+            joins.append((int(a.sum()), int(b.sum()), ext(a), ext(b),
                           cap(lv_post[si][li - 1]), em.shape[1]))
     a_pre, a_post = lv_pre[0][-1], lv_post[0][-1]
     for gi, js in enumerate(plan.l0_joins):
         b_pre, b_post = lv_pre[gi + 1][-1], lv_post[gi + 1][-1]
         d = js.max_new
-        joins.append((min(n(a_post.fresh), d), appended(b_pre, b_post),
-                      d, cap(b_post)))
-        joins.append((n(a_pre.valid), min(n(b_post.fresh), d),
-                      cap(a_post), d))
+        da, db = min(n(a_post.fresh), d), min(n(b_post.fresh), d)
+        b = held(b_pre, b_post)
+        joins.append((da, int(b.sum()), da, ext(b), d, cap(b_post)))
+        a = row(a_pre.valid)
+        joins.append((int(a.sum()), db, ext(a), db, cap(a_post), d))
         a_pre, a_post = pre.engines.l0[gi], post.engines.l0[gi]
     live = lambda ts: sum(n(t.valid) for t in ts)
     return ((live(tables), sum(cap(t) for t in tables),
@@ -236,15 +244,16 @@ def test_tick_load_equals_host_counts():
         chunk = edges[state["i"]:info.n_edges_ingested]
         state["i"] = info.n_edges_ingested
         (batch,) = to_batches(chunk, quantize_pow2(len(chunk), lo=16))
-        want = [0, 0, 0, 0]
+        want = [0, 0, 0, 0, 0]
         for k in range(svc.slots_per_group):
             tables, joins = host_load(plan, state["pre"], post, batch, k)
             want[0] += tables[0] + tables[2]
             want[1] += tables[1] + tables[3]
-            want[2] += sum(a * b for a, b, _, _ in joins)
-            want[3] += sum(a * b for _, _, a, b in joins)
+            want[2] += sum(j[0] * j[1] for j in joins)
+            want[3] += sum(j[4] * j[5] for j in joins)
+            want[4] += sum(j[2] * j[3] for j in joins)
         got = [info.live_rows, info.capacity_rows, info.live_pairs,
-               info.capacity_pairs]
+               info.capacity_pairs, info.swept_pairs]
         assert got == want, (info.tick, got, want)
         infos.append(info)
         state["pre"] = post
@@ -255,6 +264,10 @@ def test_tick_load_equals_host_counts():
     assert max(i.live_pairs for i in infos) > 0
     assert all(0 <= i.live_rows <= i.capacity_rows for i in infos)
     assert all(0 <= i.live_pairs <= i.capacity_pairs for i in infos)
+    assert all(i.live_pairs <= i.swept_pairs <= i.capacity_pairs
+               for i in infos)
+    # expiry leaves holes below the extents: the kernel sweeps them too
+    assert any(i.swept_pairs > i.live_pairs for i in infos)
     # per slot, the fixed-batch ingest path returns the same counters
     pre = jax.device_get(g.sstate)
     (batch,) = to_batches(stream(16, seed=9), 16)
@@ -267,9 +280,9 @@ def test_tick_load_equals_host_counts():
         load = TickLoad.unpack(jax.device_get(out[qid].load))
         assert (int(load.level_live), int(load.level_cap),
                 int(load.l0_live), int(load.l0_cap)) == tables
-        assert list(zip(load.join_live_a.tolist(), load.join_live_b.tolist(),
-                        load.join_cap_a.tolist(),
-                        load.join_cap_b.tolist())) == joins
+        assert list(zip(*(x.tolist() for x in (
+            load.join_live_a, load.join_live_b, load.join_ext_a,
+            load.join_ext_b, load.join_cap_a, load.join_cap_b)))) == joins
 
 
 def test_tick_load_gauges():
