@@ -324,12 +324,15 @@ class StreamSession:
         a pure device-data write, no XLA recompilation.  Admission
         control: if that structure's live slot tables have already
         overflowed, registration raises ``AdmissionError`` (the new
-        tenant would silently lose matches) unless ``force=True``.
+        tenant would silently lose matches) unless ``force=True``.  The
+        overflow counters move only in ticks, so before the first tick
+        there is nothing to read: tenants registered ahead of the stream
+        cost no device round trip each.
         """
         plan = (pattern if isinstance(pattern, PatternPlan)
                 else compile_pattern(pattern, self.vocab))
         eplan = self.service.registry.compile(plan.query, plan.window)
-        if not force:
+        if not force and self.service.n_ticks:
             pressure = self.service.overflow_pressure(plan_signature(eplan))
             if pressure:
                 raise AdmissionError(

@@ -194,8 +194,8 @@ def init_slot_state(template_plan: ExecutionPlan, n_slots: int,
 
 
 def _put_row(full, row, k):
-    """``full[k] = row``, keeping ``full``'s (possibly mesh-) sharding."""
-    return full.at[k].set(row, out_sharding=jax.typeof(full).sharding)
+    """``full[k] = row``: an update slice, each replica writing only its own block."""
+    return jax.lax.dynamic_update_index_in_dim(full, jnp.asarray(row, full.dtype), k, 0)
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))

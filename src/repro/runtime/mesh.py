@@ -405,9 +405,10 @@ class ShardedSearchService(ContinuousSearchService):
         return res
 
     def last_mesh_stats(self) -> dict[int, dict]:
-        """Host values of every group's last-tick ``MeshTickStats``."""
+        """Host values of every group's last-tick ``MeshTickStats``, all
+        read in one ``jax.device_get``."""
         return {gid: {k: int(v) for k, v in s._asdict().items()}
-                for gid, s in self.mesh_stats.items()}
+                for gid, s in jax.device_get(self.mesh_stats).items()}
 
     def _register_obs_gauges(self) -> None:
         super()._register_obs_gauges()
@@ -420,12 +421,36 @@ class ShardedSearchService(ContinuousSearchService):
             "mesh.replica_pressure_max",
             lambda: max(self.replica_pressure(), default=0))
 
-    def _trace_tick_extras(self, tr) -> None:
-        # the collectives run inside the jitted mesh tick; their psum/
-        # pmax scalars are already on host-reachable device buffers
-        # after the barrier, so reading them here adds no sync point
-        for gid, s in self.last_mesh_stats().items():
-            tr.event("mesh.collectives", gid=gid, **s)
+    def _trace_tick_extras(self, tr, tick_span, ticked) -> None:
+        """The tick's collective scalars and its table rows per replica.
+
+        Every ticked group's ``MeshTickStats`` comes back in ONE
+        ``jax.device_get`` (the tick has passed its barrier, so the read
+        waits on nothing) and becomes an event ``mesh.collectives`` with
+        the fields ``gid``; ``n_matches`` and ``n_overflow``, the psums
+        of the group's new matches and dropped appends; ``t_clock``, the
+        pmax of its replicas' engine clocks; and ``live_rows`` and
+        ``capacity_rows``, the psums of its live and allocated table
+        rows.  The ``tick`` span gets their sums over the groups,
+        ``mesh_matches`` and ``mesh_live_rows`` (equal to the host's
+        counts), and per replica the live and allocated rows of its slot
+        block, ``replica_live_rows`` and ``replica_capacity_rows``, split
+        from the per-slot ``TickLoad`` already read back for delivery.
+        """
+        stats = jax.device_get([self.mesh_stats[g.gid] for g, _ in ticked])
+        live = np.zeros(self.n_replicas, np.int64)
+        cap = np.zeros(self.n_replicas, np.int64)
+        for (g, load), s in zip(ticked, stats):
+            tr.event("mesh.collectives", gid=g.gid,
+                     **{k: int(v) for k, v in s._asdict().items()})
+            live += (load.level_live + load.l0_live).reshape(
+                self.n_replicas, -1).sum(axis=1)
+            cap += (load.level_cap + load.l0_cap).reshape(
+                self.n_replicas, -1).sum(axis=1)
+        tick_span.set(mesh_matches=sum(int(s.n_matches) for s in stats),
+                      mesh_live_rows=sum(int(s.live_rows) for s in stats),
+                      replica_live_rows=live.tolist(),
+                      replica_capacity_rows=cap.tolist())
 
     # -------------------------------------------------------------- #
     # checkpoint / restore

@@ -660,16 +660,15 @@ class ContinuousSearchService:
                     [g.sstate for g in active]
                     + ([] if self.forest is None else self.forest.states()))
             lat_ms = (time.perf_counter() - t0) * 1e3
-            if tr is not None:
-                self._trace_tick_extras(tr)
             with maybe_span(tr, "tick.deliver") as deliver:
-                n_matches, tick_overflow, load = self._deliver(
+                n_matches, tick_overflow, load, ticked = self._deliver(
                     results, on_match, totals)
             if tr is not None:
                 deliver.set(n_matches=n_matches)
                 tick_span.set(chunk=len(chunk), live_rows=load[0],
                               capacity_rows=load[1], live_pairs=load[2],
                               capacity_pairs=load[3], swept_pairs=load[4])
+                self._trace_tick_extras(tr, tick_span, ticked)
         self.n_ticks += 1
         self.n_edges_ingested += len(chunk)
         obs = self.obs
@@ -691,13 +690,15 @@ class ContinuousSearchService:
             capacity_pairs=load[3], swept_pairs=load[4])
 
     def _deliver(self, results, on_match, totals: dict
-                 ) -> tuple[int, int, tuple[int, int, int, int, int]]:
+                 ) -> tuple[int, int, tuple[int, int, int, int, int], list]:
         """Read back each group's tick result and deliver its matches.
         Returns (new matches, overflow, ``TickLoad.totals()`` summed over
-        the groups)."""
+        the groups, and with a tracer each group with its ``TickLoad``:
+        ``(group, load)`` pairs for ``_trace_tick_extras``)."""
         tr = self.tracer
         n_matches = tick_overflow = 0
         load = (0, 0, 0, 0, 0)
+        ticked = []
         for g, res in results:
             armed = [(k, qid) for k, qid in enumerate(g.qids)
                      if qid is not None]
@@ -719,13 +720,17 @@ class ContinuousSearchService:
                     if n_new and rows is not None:
                         mb, me, mv = (x[k] for x in rows)
                         on_match(qid, mb[mv], me[mv])
-            load = tuple(a + b for a, b in
-                         zip(load, TickLoad.unpack(g_load).totals()))
-        return n_matches, tick_overflow, load
+            g_tick = TickLoad.unpack(g_load)
+            load = tuple(a + b for a, b in zip(load, g_tick.totals()))
+            if tr is not None:
+                ticked.append((g, g_tick))
+        return n_matches, tick_overflow, load, ticked
 
-    def _trace_tick_extras(self, tr: Tracer) -> None:
-        """Tracer-on hook after the tick barrier — the mesh service
-        emits its collective scalars here; base service has none."""
+    def _trace_tick_extras(self, tr: Tracer, tick_span, ticked) -> None:
+        """Tracer-on hook at the end of a tick, with the open ``tick``
+        span and each ticked group with its ``TickLoad`` (as read back
+        for delivery).  The mesh service adds its collective scalars and
+        per-replica rows here; the base service has none."""
 
     def _observe_coalescer(self, coalescer: TickCoalescer) -> None:
         """Mirror the AIMD decision just taken into ``coalescer.*``

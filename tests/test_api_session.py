@@ -222,6 +222,34 @@ def test_overflow_degrades_status_and_gates_admission():
     assert tri.status == ACTIVE
 
 
+def test_admission_reads_no_counters_before_the_first_tick(monkeypatch):
+    """Counters move only in ticks, so registering tenants ahead of the
+    stream reads nothing back from the device; once a tick has run,
+    admission reads the structure's counters again."""
+    import repro.runtime.service as service_mod
+
+    reads = []
+
+    class CountingNumpy:
+        def __getattr__(self, name):
+            return getattr(np, name)
+
+        def asarray(self, *args, **kw):
+            reads.append(args[0])
+            return np.asarray(*args, **kw)
+
+    sess = StreamSession(slots_per_group=2, **CAP)
+    monkeypatch.setattr(service_mod, "np", CountingNumpy())
+    subs = [sess.register(chain_pattern()) for _ in range(3)]
+    assert reads == [] and sess.service.overflow_pressure() == 0
+    sess.serve(traffic(32, seed=3), batch_size=32, min_batch=32,
+               max_batch=32)
+    reads.clear()
+    subs.append(sess.register(chain_pattern()))
+    assert len(reads) == 2              # one read per live group
+    assert all(s.status == ACTIVE for s in subs)
+
+
 def test_session_checkpoint_restore_roundtrip(tmp_path):
     """Crash/restore on the api surface: original qids, same vocab ids,
     same pattern plans, window matches identical; replaying the tail

@@ -6,9 +6,11 @@ that is described, not attached.  It refuses what interpret mode and the
 ``repro.analysis`` KC rules cannot see (block shapes, layouts, scalar
 stores to VMEM), so these compiles guard every change to the kernels and
 the slot tick at the widths ``chip_smoke.py`` serves: tables of 16,384
-rows joined against 1,024-edge batches, 64 slots per group; and the L0
+rows joined against 1,024-edge batches, 64 slots per group; the L0
 delta joins of the ``netflow-w1`` benchmark deployment, whose 16,384-row
-sides the pairs kernel holds whole in VMEM.
+sides the pairs kernel holds whole in VMEM; and the replica-sharded tick
+of ``netflow-w1-x4``, the slot tick under ``shard_map`` over the four
+chips of a described v5e host.
 
 The topology is described inside a module fixture, never at import: one
 process at a time may load the TPU library, so only the test worker that
@@ -22,21 +24,22 @@ import pytest
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
 from repro.core.join import JoinBackend
-from repro.core.multi import build_slot_tick, init_slot_state
+from repro.core.multi import _arm_slot, build_slot_tick, init_slot_state
 from repro.core.plan import compile_plan
 from repro.core.query import QueryGraph
-from repro.core.state import EdgeBatch
+from repro.core.state import EdgeBatch, init_state
 from repro.kernels.compat_join import ops as cj_ops
+from repro.runtime.mesh import build_mesh_slot_tick
 
 CA, CB, SLOTS, MAX_NEW = 16384, 1024, 64, 2048
 NVA, NEA, NVB, NEB = 4, 3, 2, 1
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
@@ -50,9 +53,14 @@ def one_chip():
     prev = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", prev)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
 
 
 def _spec():
@@ -159,3 +167,53 @@ def test_pallas_slot_tick_compiles_with_kernels(one_chip):
     wm = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
     text = _compiled_text(tick, sstate, batch, wm)
     assert "tpu_custom_call" in text
+
+
+def _mesh_group(topo):
+    """A replica-sharded group at deployment capacity, 64 slots on each
+    of the host's four chips: its mesh, plan, state shapes and the
+    replicated sharding."""
+    mesh = jax.make_mesh((4,), ("replica",), devices=topo.devices[:4],
+                         axis_types=(jax.sharding.AxisType.Auto,))
+    plan = compile_plan(_c2_query(), 600, level_capacity=CA,
+                        l0_capacity=CA, max_new=MAX_NEW)
+    sharded, repl = NamedSharding(mesh, P("replica")), NamedSharding(mesh, P())
+    sstate = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharded),
+        jax.eval_shape(lambda: init_slot_state(plan, 4 * SLOTS)))
+    return mesh, plan, sstate, repl
+
+
+def test_mesh_slot_tick_compiles_on_four_chips(topo):
+    """The served tick of a replica-sharded group compiles for the host:
+    the PALLAS kernels inside ``shard_map``, and the closing psum/pmax
+    of ``MeshTickStats`` as all-reduces."""
+    mesh, plan, sstate, repl = _mesh_group(topo)
+    tick = build_mesh_slot_tick(plan, mesh, backend=JoinBackend.PALLAS)
+    batch = EdgeBatch(*(
+        [jax.ShapeDtypeStruct((CB,), jnp.int32, sharding=repl)] * 6
+        + [jax.ShapeDtypeStruct((CB,), jnp.bool_, sharding=repl)]))
+    wm = jax.ShapeDtypeStruct((), jnp.int32, sharding=repl)
+    text = _compiled_text(tick, sstate, batch, wm)
+    assert "tpu_custom_call" in text
+    assert "all-reduce" in text
+
+
+def test_mesh_slot_write_stays_on_its_replica(topo):
+    """Registering a tenant writes one slot of a replica-sharded group in
+    place on the replica that owns it: no collective moves the group's
+    tables, and the state keeps its sharding."""
+    _, plan, sstate, repl = _mesh_group(topo)
+    empty = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=repl),
+        jax.eval_shape(lambda: init_state(plan)))
+    params = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape[1:], x.dtype, sharding=repl),
+        sstate.params)
+    k = jax.ShapeDtypeStruct((), jnp.int32, sharding=repl)
+    compiled = _arm_slot.lower(sstate, k, empty, params).compile()
+    text = compiled.as_text()
+    assert not [op for op in ("all-gather", "all-reduce", "all-to-all",
+                              "collective-permute") if op in text]
+    assert {s.spec for s in jax.tree.leaves(compiled.output_shardings)} \
+        == {P("replica")}

@@ -11,7 +11,8 @@
   tick (REF backend, small tables), every tick sweeps at least its live
   pairs, and ``ServeInfo`` carries their totals; on 4
   virtual devices ``ShardedSearchService`` reports the same totals, and
-  its psum'd ``MeshTickStats`` agree (subprocess).
+  its psum'd ``MeshTickStats`` and per-replica rows agree (subprocess);
+  on one device a mesh ``tick`` span carries them and a plain one not.
 * ``ingest.hold_ms``: exact holds on a ``ScriptedSource`` with a
   scripted clock.
 * The lowered slot tick carries every ``engine.*`` scope.
@@ -40,6 +41,7 @@ from repro.core.plan import compile_plan
 from repro.core.query import QueryGraph
 from repro.core.state import make_batch
 from repro.obs import MetricsRegistry, memory_tracer, summarize_trace
+from repro.runtime.mesh import ShardedSearchService
 from repro.runtime.service import ContinuousSearchService
 from repro.runtime.straggler import quantize_pow2
 from repro.stream.generator import StreamConfig, synth_traffic_stream, to_batches
@@ -310,6 +312,38 @@ def test_tick_load_agrees_on_a_mesh():
         env=env, capture_output=True, text=True, timeout=600)
     assert proc.returncode == 0, proc.stdout + "\n" + proc.stderr
     assert "LOAD-MESH-OK" in proc.stdout
+
+
+def test_mesh_tick_span_carries_collectives_and_replica_rows():
+    svc = ShardedSearchService(n_replicas=1, slots_per_replica=2,
+                               backend=JoinBackend.REF,
+                               tick_cache=SlotTickCache(), **CAP)
+    plain = ContinuousSearchService(slots_per_group=2,
+                                    backend=JoinBackend.REF,
+                                    tick_cache=SlotTickCache(), **CAP)
+    spans = {}
+    for name, s in (("mesh", svc), ("plain", plain)):
+        s.tracer, buf = memory_tracer()
+        s.register(chain2(), 20)
+        s.register(split_query(), 20)
+        s.serve_stream(stream(96), **BATCH)
+        s.tracer.flush()
+        spans[name] = [json.loads(x) for x in buf.getvalue().splitlines()]
+    ticks = [s for s in spans["mesh"] if s["span"] == "tick"]
+    assert ticks
+    for tick in ticks:
+        kids = [s for s in spans["mesh"] if s["parent"] == tick["id"]]
+        deliver = next(s for s in kids if s["span"] == "tick.deliver")
+        events = [s for s in kids if s["span"] == "mesh.collectives"]
+        assert sorted(e["gid"] for e in events) == [0, 1]
+        assert tick["mesh_matches"] == deliver["n_matches"] \
+            == sum(e["n_matches"] for e in events)
+        assert tick["mesh_live_rows"] == tick["live_rows"]
+        assert tick["replica_live_rows"] == [tick["live_rows"]]
+        assert tick["replica_capacity_rows"] == [tick["capacity_rows"]]
+    assert any(t["mesh_matches"] for t in ticks)
+    assert not [s for s in spans["plain"]
+                if "replica_live_rows" in s or s["span"] == "mesh.collectives"]
 
 
 # ------------------------------------------------------------------ #
